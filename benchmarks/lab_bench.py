@@ -254,58 +254,71 @@ def bench_chunks(n_nodes: int, n_intervals: int, n_configs: int,
     return rows
 
 
+def _time_device_count(n_nodes: int, n_intervals: int, n_configs: int,
+                       ndev: int) -> dict:
+    """Best-of-3 sweep wall time with the gain axis on ``ndev`` devices."""
+    from repro.core.cluster_sim import paper_controller_params
+    from repro.core.traces import fleet_demand_traces
+    from repro.lab import grid_gains, sweep_demand
+
+    p = paper_controller_params()
+    demand = fleet_demand_traces(n_nodes, n_intervals, p.interval_s, seed=0)
+    k = max(int(np.sqrt(n_configs)), 2)
+    gains = grid_gains(p, lam=np.linspace(0.1, 1.8, k),
+                       r0=np.linspace(0.88, 0.98, k))
+    el = _best(lambda: sweep_demand(demand, gains,
+                                    node_memory=p.total_memory,
+                                    interval_s=p.interval_s, devices=ndev))
+    return {"elapsed_s": el, "n_configs": len(gains)}
+
+
 _SCALING_SNIPPET = r"""
-import os, json, time, sys
+import json, os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
-import numpy as np
-from repro.core.cluster_sim import paper_controller_params
-from repro.core.traces import fleet_demand_traces
-from repro.lab import grid_gains, sweep_demand
-n_nodes, n_intervals, n_configs, ndev = %d, %d, %d, %d
-p = paper_controller_params()
-demand = fleet_demand_traces(n_nodes, n_intervals, p.interval_s, seed=0)
-k = max(int(np.sqrt(n_configs)), 2)
-gains = grid_gains(p, lam=np.linspace(0.1, 1.8, k),
-                   r0=np.linspace(0.88, 0.98, k))
-run = lambda: sweep_demand(demand, gains, node_memory=p.total_memory,
-                           interval_s=p.interval_s, devices=ndev)
-run()
-best = 1e9
-for _ in range(3):
-    t0 = time.perf_counter()
-    run()
-    best = min(best, time.perf_counter() - t0)
-print(json.dumps({"elapsed_s": best, "n_configs": len(gains)}))
+sys.path.insert(0, %r)
+from lab_bench import _time_device_count
+print(json.dumps(_time_device_count(%d, %d, %d, %d)))
 """
 
 
 def bench_device_scaling(n_nodes: int, n_intervals: int, n_configs: int,
-                         device_counts=(1, 2)) -> list:
-    """Gain-axis shard_map scaling over forced host devices.
+                         device_counts=None) -> list:
+    """Gain-axis shard_map scaling over 1 and several devices.
 
-    Each count runs in a subprocess because XLA fixes the host device
-    count at first jax init.
+    On an accelerator every count runs in this process over the real
+    local devices (default: 1 and all of them), since a chip belongs to
+    one process.  On the CPU each count runs in a subprocess with
+    forced host devices (default: 1 and 2), because XLA fixes the host
+    device count at first jax init.  A failed count raises.
     """
+    import jax
+
+    on_cpu = jax.default_backend() == "cpu"
+    if device_counts is None:
+        device_counts = ((1, 2) if on_cpu else
+                         tuple(sorted({1, len(jax.local_devices())})))
+    here = os.path.dirname(os.path.abspath(__file__))
     rows = []
     for ndev in device_counts:
-        code = _SCALING_SNIPPET % (ndev, n_nodes, n_intervals, n_configs,
-                                   ndev)
-        env = dict(os.environ)
-        env["PYTHONPATH"] = env.get("PYTHONPATH") or "src"
-        proc = subprocess.run([sys.executable, "-c", code],
-                              capture_output=True, text=True, env=env,
-                              timeout=1800)
-        if proc.returncode != 0:
-            print(f"# device_scaling ndev={ndev} failed:\n"
-                  f"{proc.stderr[-1500:]}", file=sys.stderr)
-            continue
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
+        if not on_cpu:
+            out = _time_device_count(n_nodes, n_intervals, n_configs, ndev)
+        else:
+            code = _SCALING_SNIPPET % (ndev, here, n_nodes, n_intervals,
+                                       n_configs, ndev)
+            env = dict(os.environ)
+            env["PYTHONPATH"] = env.get("PYTHONPATH") or "src"
+            proc = subprocess.run([sys.executable, "-c", code],
+                                  capture_output=True, text=True, env=env,
+                                  timeout=1800)
+            if proc.returncode != 0:
+                raise RuntimeError(f"device_scaling ndev={ndev} failed:\n"
+                                   f"{proc.stderr[-1500:]}")
+            out = json.loads(proc.stdout.strip().splitlines()[-1])
         rows.append(_row(f"devices_{ndev}", n_nodes, n_intervals,
                          out["n_configs"], out["elapsed_s"]))
-    if rows:
-        base = rows[0]["throughput_upd_per_s"]
-        for r in rows:
-            r["scaling_vs_1_device"] = r["throughput_upd_per_s"] / base
+    base = rows[0]["throughput_upd_per_s"]
+    for r in rows:
+        r["scaling_vs_1_device"] = r["throughput_upd_per_s"] / base
     return rows
 
 

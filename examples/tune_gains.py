@@ -32,6 +32,7 @@ from repro.core import (GiB, MemoryPlane, NodeSpec, PlaneSpec, ShardCache,
                         SimulatedMonitor, StoreSpec)
 from repro.lab import (OBJECTIVES, get_scenario, list_scenarios, tune_gains,
                        tune_portfolio)
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def tune_one(name: str, budget: int, method: str = "grid",
@@ -143,9 +144,10 @@ def main() -> None:
                     help="worst-case tune one gain set across these "
                          "scenarios instead of single-scenario tuning")
     ap.add_argument("--engine", default="xla", choices=("xla", "pallas"),
-                    help="sweep engine: the default XLA scan or PR 9's "
+                    help="sweep engine: the default XLA scan or the "
                          "fused PallasSweep kernel")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.check_presets:
         sys.exit(check_presets(args.budget, args.engine))

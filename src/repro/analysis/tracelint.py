@@ -54,7 +54,6 @@ _TRACED_ARG_POS: Dict[str, Tuple[int, ...]] = {
     "jax.lax.while_loop": (0, 1),
     "jax.lax.cond": (1, 2),
     "jax.shard_map": (0,),
-    "jax.experimental.shard_map.shard_map": (0,),
     "jax.experimental.pallas.pallas_call": (0,),
 }
 

@@ -108,9 +108,14 @@ class HostMemoryMonitor:
 class DeviceMemoryMonitor:
     """Samples one accelerator's HBM via ``device.memory_stats()``.
 
-    On CPU backends memory_stats() is unavailable; ``total`` falls back to
-    the configured ``assumed_total`` so control logic stays exercisable.
+    The CPU backend reports no memory stats; there ``total`` is the
+    configured ``assumed_total`` and ``used`` is 0, so control logic
+    stays exercisable.  An accelerator must report ``bytes_limit`` and
+    ``bytes_in_use``: construction raises ``ValueError`` if it does not,
+    and a later sample without them raises :class:`MonitorFault`.
     """
+
+    _KEYS = ("bytes_limit", "bytes_in_use")
 
     def __init__(self, device, node: Optional[str] = None,
                  assumed_total: float = 16 * 2**30,
@@ -119,15 +124,29 @@ class DeviceMemoryMonitor:
         self.node = node or f"{device.platform}:{device.id}"
         self.assumed_total = assumed_total
         self._storage_used_fn = storage_used_fn or (lambda: 0.0)
+        if device.platform != "cpu":
+            missing = self._missing(device.memory_stats())
+            if missing:
+                raise ValueError(
+                    f"{device.platform} device {device.id} reports no "
+                    f"{missing} in memory_stats()")
+
+    def _missing(self, stats) -> list:
+        return [k for k in self._KEYS if k not in (stats or {})]
 
     def sample(self) -> MemorySample:
-        stats = {}
-        try:
-            stats = self.device.memory_stats() or {}
-        except Exception:
-            stats = {}
-        total = float(stats.get("bytes_limit", self.assumed_total))
-        used = float(stats.get("bytes_in_use", 0.0))
+        stats = self.device.memory_stats()
+        if self.device.platform == "cpu":
+            stats = stats or {}
+            total = float(stats.get("bytes_limit", self.assumed_total))
+            used = float(stats.get("bytes_in_use", 0.0))
+        else:
+            missing = self._missing(stats)
+            if missing:
+                raise MonitorFault(f"{self.node}: memory_stats() lacks "
+                                   f"{missing}")
+            total = float(stats["bytes_limit"])
+            used = float(stats["bytes_in_use"])
         return MemorySample(
             node=self.node, timestamp=time.time(), used=used, total=total,
             storage_used=float(self._storage_used_fn()),

@@ -35,7 +35,7 @@ from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from ..analysis.runtime import (dispatch_guard, record_trace,
                                 sanitizers_enabled)
@@ -45,8 +45,8 @@ from ..lab.score import (FleetStats, OVER_R0_EPS, SETTLE_TOL,
                          compute_fleet_stats, finalize_fleet_stats,
                          kahan_add, quantile_from_codes, utilization_codes)
 from ..lab._compat import warn_once
-from ..lab.sweep import (GainSet, _resolve_engine, _shard_map,
-                         resolve_devices)
+from ..lab.sweep import (GainSet, _resolve_engine, _stager, resolve_devices,
+                         sweep_mesh)
 from .arbiter import MIN_TENANT_BUDGET, arbitrate, arbitrate_reference
 from .specs import FleetSpec
 
@@ -263,20 +263,18 @@ def _compiled_fleet_sweep(devices: Tuple, policy: str,
                            node_shards=node_shards)
     if len(devices) <= 1:
         return jax.jit(fn)
-    gains_specs = (P("gains"),) * 7
-    if node_shards == 1:
-        mesh = Mesh(np.asarray(devices), ("gains",))
-        in_specs = ((P(None, None, None, None), P(None), P(None), P(None))
-                    + gains_specs + (P(),))
-    else:
-        grid = np.asarray(devices).reshape(
-            len(devices) // node_shards, node_shards)
-        mesh = Mesh(grid, ("gains", "nodes"))
-        in_specs = ((P(None, None, None, "nodes"), P("nodes"), P(None),
-                     P(None)) + gains_specs + (P(),))
-    mapped = _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                        out_specs=P("gains"), check_rep=False)
+    mapped = jax.shard_map(fn, mesh=sweep_mesh(devices, node_shards),
+                           in_specs=(_fleet_specs(node_shards)
+                                     + (P("gains"),) * 7 + (P(),)),
+                           out_specs=P("gains"), check_vma=False)
     return jax.jit(mapped)
+
+
+def _fleet_specs(node_shards: int) -> Tuple:
+    """Partition specs of demand, node memory, weights and floors."""
+    if node_shards == 1:
+        return (P(None, None, None, None), P(None), P(None), P(None))
+    return (P(None, None, None, "nodes"), P("nodes"), P(None), P(None))
 
 
 def fleet_sweep_demand(
@@ -371,15 +369,17 @@ def fleet_sweep_demand(
         gains = gains.concat(pad)
     fn = _compiled_fleet_sweep(devs, policy, tuple(priority_order),
                                node_shards)
-    demand_dev = jnp.asarray(demand_e)
-    m_dev = jnp.asarray(m)
-    w_dev = jnp.asarray(weights, jnp.float32)
-    fl_dev = jnp.asarray(floors, jnp.float32)
-    gain_dev = [jnp.asarray(getattr(gains, f.name), jnp.float32)
-                for f in dataclasses.fields(GainSet)]
-    iv = jnp.asarray(np.float32(interval_s))
-    cols_per_chunk = [[a[lo:lo + chunk] for a in gain_dev]
-                     for lo in range(0, len(gains), chunk)]
+    stage = _stager(devs, node_shards)
+    lead_p = _fleet_specs(node_shards)
+    demand_dev = stage(demand_e, lead_p[0])
+    m_dev = stage(m, lead_p[1])
+    w_dev = stage(weights.astype(np.float32), lead_p[2])
+    fl_dev = stage(floors.astype(np.float32), lead_p[3])
+    gain_cols = [np.asarray(getattr(gains, f.name), np.float32)
+                 for f in dataclasses.fields(GainSet)]
+    iv = stage(np.float32(interval_s), P())
+    cols_per_chunk = [[stage(a[lo:lo + chunk], P("gains")) for a in gain_cols]
+                      for lo in range(0, len(gains), chunk)]
     if sanitizers_enabled():
         jax.block_until_ready(fn(
             demand_dev, m_dev, w_dev, fl_dev, *cols_per_chunk[0], iv))

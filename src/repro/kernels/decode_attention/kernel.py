@@ -8,6 +8,11 @@ recurrence as the flash kernel, degenerate q-block of one token per
 (SMEM) so block-level skipping -- tiles entirely past ``len_b`` issue no
 matmul -- is decided before the tile loads stream.
 
+Mosaic tiles the last two dims of every block by (8, 128) unless a dim
+is whole, so one program takes a KV head's whole group of query heads,
+a (group, hd) block, against a (block_k, hd) tile of the cache viewed
+head-major, (B, KV, S, hd).
+
 This kernel is what the DynIMS-managed KV pool feeds: the pool hands out
 whole cache pages, the engine materializes the (B,S,KV,hd) view, the
 kernel never reads past ``lengths``.
@@ -28,11 +33,9 @@ NEG_INF = -1e30
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *,
                    scale: float, window: int, block_k: int,
-                   n_kv_blocks: int, n_heads: int):
-    bh = pl.program_id(0)
+                   n_kv_blocks: int, n_kv_heads: int):
     ik = pl.program_id(1)
-    b = bh // n_heads
-    seq_len = len_ref[b]
+    seq_len = len_ref[pl.program_id(0) // n_kv_heads]
 
     @pl.when(ik == 0)
     def _init():
@@ -47,28 +50,30 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, 0, :].astype(jnp.float32)               # (hd,)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        s = jnp.dot(k, q, preferred_element_type=jnp.float32) * scale
-        k_pos = k_start + jax.lax.iota(jnp.int32, block_k)
+        q = q_ref[...].astype(jnp.float32)                   # (g, hd)
+        k = k_ref[...].astype(jnp.float32)                   # (bk, hd)
+        v = v_ref[...].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s * scale                                        # (g, bk)
+        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         valid = k_pos < seq_len
         if window:
             valid &= k_pos >= seq_len - window
-        s = jnp.where(valid, s, NEG_INF)                     # (bk,)
-        m_prev = m_ref[0]
-        m_new = jnp.maximum(m_prev, s.max())
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...]                                  # (g, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[0] = l_ref[0] * corr + p.sum()
-        acc_ref[0, :] = acc_ref[0, :] * corr + jnp.dot(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
             p, v, preferred_element_type=jnp.float32)
-        m_ref[0] = m_new
+        m_ref[...] = m_new
 
     @pl.when(ik == n_kv_blocks - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[0], 1e-30)
-        o_ref[0, 0, :] = (acc_ref[0, :] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
@@ -83,35 +88,34 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     block_k = min(block_k, s)
     assert s % block_k == 0, "cache length must divide block_k"
     n_k = s // block_k
-    grid = (b * h, n_k)
+    grid = (b * kvh, n_k)
 
     kernel = functools.partial(
         _decode_kernel, scale=1.0 / (hd ** 0.5), window=window,
-        block_k=block_k, n_kv_blocks=n_k, n_heads=h)
+        block_k=block_k, n_kv_blocks=n_k, n_kv_heads=kvh)
 
+    q_spec = pl.BlockSpec((None, None, g, hd),
+                          lambda bk, ik, lens: (bk // kvh, bk % kvh, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, hd),
+                           lambda bk, ik, lens: (bk // kvh, bk % kvh, ik, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda bh, ik, lens: (bh // h, bh % h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bh, ik, lens: (bh // h, ik, (bh % h) // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bh, ik, lens: (bh // h, ik, (bh % h) // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, hd),
-                               lambda bh, ik, lens: (bh // h, bh % h, 0)),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, 1), jnp.float32),
+            pltpu.VMEM((g, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        out_shape=jax.ShapeDtypeStruct((b, kvh, g, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), q.reshape(b, kvh, g, hd),
+      k_cache.transpose(0, 2, 1, 3), v_cache.transpose(0, 2, 1, 3))
+    return out.reshape(b, h, hd)

@@ -10,12 +10,16 @@ on a sequential TPU grid is real skipped work, not a predicated no-op.
 Grid: (batch*heads, q_blocks, kv_blocks) with semantics
 ("parallel", "parallel", "arbitrary") -- the kv axis must run in order
 because the scratch carry accumulates along it.
+
+Mosaic tiles the last two dims of every block by (8, 128) unless a dim
+is whole, so the wrapper views q/k/v head-major, (B, H, S, hd), and a
+block is one head's (block, hd) tile; the running max and sum are
+(block_q, 1) columns.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -51,9 +55,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)          # (bq, hd)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)          # (bk, hd)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)                  # (bq, hd)
+        k = k_ref[...].astype(jnp.float32)                  # (bk, hd)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         s = s * scale                                       # (bq, bk)
@@ -68,12 +72,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
             mask &= k_pos > q_pos - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                 # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -81,7 +85,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ik == n_kv_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -105,26 +109,23 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         _flash_kernel, scale=1.0 / (hd ** 0.5), causal=causal,
         window=window, block_q=block_q, block_k=block_k, n_kv_blocks=n_k)
 
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, block_q, hd),
+                          lambda bh, iq, ik: (bh // h, bh % h, iq, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, hd),
+                           lambda bh, iq, ik: (bh // h, (bh % h) // g, ik, 0))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, hd),
-                         lambda bh, iq, ik: (bh // h, iq, bh % h, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bh, iq, ik: (bh // h, ik, (bh % h) // g, 0)),
-            pl.BlockSpec((1, block_k, 1, hd),
-                         lambda bh, iq, ik: (bh // h, ik, (bh % h) // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, hd),
-                               lambda bh, iq, ik: (bh // h, iq, bh % h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, hd), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),        # running max
-            pltpu.VMEM((block_q,), jnp.float32),        # running sum
+            pltpu.VMEM((block_q, 1), jnp.float32),      # running max
+            pltpu.VMEM((block_q, 1), jnp.float32),      # running sum
             pltpu.VMEM((block_q, hd), jnp.float32),     # running acc
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

@@ -69,7 +69,7 @@ def ssm_scan(decay: jax.Array, drive: jax.Array, h0: jax.Array, *,
                                lambda ib, icb, ic: (ib, ic, icb, 0)),
         out_shape=jax.ShapeDtypeStruct(decay.shape, jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_c, n), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(decay, drive, h0)

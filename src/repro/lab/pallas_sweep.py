@@ -10,21 +10,22 @@ accumulators -- over a stacked ``(S, L, N)`` state block:
 * **L** gain lanes, tiled :data:`TILE_GAINS` at a time,
 * **N** nodes as the vector axis.
 
-Grid ``(gain_tiles, time_chunks)`` with semantics
-``("parallel", "arbitrary")``: each program keeps its tile's full state
-in VMEM scratch across the sequential time axis, walks
+Grid ``(gain_tiles, node_tiles, time_chunks)`` with semantics
+``("parallel", "parallel", "arbitrary")``: each program keeps its
+(:data:`TILE_GAINS` x :data:`NODE_TILE`) tile's state in VMEM scratch
+across the sequential time axis, walks
 :data:`TIME_CHUNK` intervals as an unrolled vector loop, and emits the
 uint16 utilization codes the quantile bisection consumes.  Nothing of
 size T x N ever leaves the device; per segment the host sees O(L)
 scalars.
 
-**Backends.**  On CPU (every CI leg) ``engine="pallas"`` lowers the
+**Backends.**  On the CPU backend ``engine="pallas"`` lowers the
 *identical* fused step through one ``lax.scan`` -- same ops, same
 order, so parity tests and tier-1 stay runnable and fast; the true
 ``pallas_call`` executes under ``interpret=True`` only when forced
 (``PALLAS_SWEEP_INTERPRET=1`` or ``force_interpret=True``), because XLA
-emulation of a Pallas grid is ~10x slower than the native scan.  On a
-TPU backend the Mosaic kernel runs directly.  All three share
+emulation of a Pallas grid is ~10x slower than the native scan.  On an
+accelerator backend the Mosaic kernel runs, compiled.  All three share
 :func:`_fused_step`, which is the single source of truth for the step
 math.
 
@@ -32,11 +33,12 @@ math.
 and the uint16 code stream make the f32 accumulation analysis of PR 3
 carry over unchanged); ``precision="bf16"`` stores only the *demand
 stream* in bf16 -- it is read once per step and upcast before use, so
-no accumulator ever rounds through bf16.  The one deliberate numeric
-departure from the XLA engine is the cache hit-curve power:
-``f ** hit_exp`` becomes ``exp2(hit_exp * log2(f))`` (3.3x faster on
-the hot path, max observed relative difference 3.4e-7 -- far inside
-the 1e-4 parity bracket the tests pin).
+no accumulator ever rounds through bf16.  Every op matches the XLA
+engine's, the cache hit-curve ``f ** hit_exp`` included: on a TPU v5e
+an ``exp2(e * log2(f))`` spelling (4e-5 relative off XLA's pow there),
+or even a 5e-7-accurate polynomial, drifts the 4096-node closed loop
+by up to 2e-2 in the violation rate, while ``**`` lowers to the same
+pow in Mosaic and XLA and keeps the engines bit-identical.
 
 **In-scan successive halving** (:func:`halving_sweep`): the candidate
 lanes, the always-alive baseline lane, and the per-lane ``alive`` mask
@@ -87,16 +89,21 @@ from .sweep import (GainSet, _resolve_chunk, paper_law_mask,
 # whose length is not a TIME_CHUNK multiple uses its largest divisor.
 TILE_GAINS = 8
 TIME_CHUNK = 32
+# Nodes per kernel tile (lanes of the vector unit), used when it
+# divides the fleet; otherwise a tile spans the whole node axis.
+NODE_TILE = 512
 
 # f32-exact module constants, mirroring the XLA engine's
 # ``jnp.float32(...)`` trace-time casts bit for bit.
 _INV_GIB = float(np.float32(1.0 / GiB))
 _GIB_F32 = float(np.float32(GiB))
 
-# Rows of the packed per-lane parameter matrix (P, L).  The derived
-# rows (reciprocal, thresholds) are precomputed in f32 on the host with
-# the exact IEEE ops the XLA engine traces, so both engines clamp and
-# count against bit-identical constants.
+# Rows of the packed per-lane parameter matrix (P, L).  The threshold
+# rows are precomputed in f32 on the host with the exact IEEE ops the
+# XLA engine traces; the reciprocal rows are filled on device by
+# :func:`_reciprocals` with the engine's own division, which an
+# accelerator need not round like the host.  Both engines then clamp
+# and count against bit-identical constants.
 _R0, _LAM, _LAM_GRANT, _U_MIN, _U_MAX, _DB, _FF = range(7)
 _INV_R0, _THR_OVER, _THR_SETTLE = 7, 8, 9
 _N_PARAM_ROWS = 10
@@ -170,21 +177,6 @@ def _state_names(paper_law: bool, has_cache: bool) -> Tuple[str, ...]:
     return tuple(names)
 
 
-def _fast_pow(x, e: float):
-    """``x ** e`` for x in [0, 1] via exp2/log2 (3.3x the pow op).
-
-    Exact at the trace-time-special exponents (e in {0, 1}); elsewhere
-    accurate to ~4e-7 relative, with ``x == 0`` mapping to ~1e-12
-    instead of 0 (the 1e-30 clamp) -- both far inside the engine parity
-    bracket.
-    """
-    if e == 1.0:
-        return x
-    if e == 0.0:
-        return jnp.ones_like(x)
-    return jnp.exp2(e * jnp.log2(jnp.maximum(x, 1e-30)))
-
-
 def _warm_fraction0(cols, rows, con: _EngineConsts):
     """Warm-seeded working-set fraction ``wf0`` per (lane, node)."""
     res0 = con.warm_frac * jnp.minimum(cols[_U_MAX], rows[_W])
@@ -202,8 +194,9 @@ def _fused_step(state, d, t, cols, rows, wf0, con: _EngineConsts,
     ``repro.lab.sweep._one_gain_stream`` op for op (law via
     :func:`vectorized_step`, Kahan accumulators, cold-scan cache carry)
     with lane-column parameters ``cols[row]`` of shape (L, 1)
-    broadcasting against node rows ``rows[row]`` of shape (N,); the one
-    departure is :func:`_fast_pow` on the hit curve.
+    broadcasting against node rows ``rows[row]`` of shape (N,), down to
+    the hit curve's ``**``: the closed loop amplifies any other pow
+    spelling's last-bit differences past the parity bracket.
 
     ``state`` is a *tuple* of per-row (L, N) planes, not the stacked
     (S, L, N) block: a stacked scan carry forces XLA's CPU backend to
@@ -254,7 +247,7 @@ def _fused_step(state, d, t, cols, rows, wf0, con: _EngineConsts,
         res_ev = jnp.minimum(resident, u_next)
         ev_g = (resident - res_ev) * _INV_GIB
         f = jnp.minimum(res_ev * rows[_INV_W], 1.0)
-        hit = con.conc * _fast_pow(f, con.hit_exp) + (1.0 - con.conc) * f
+        hit = con.conc * f ** con.hit_exp + (1.0 - con.conc) * f
         scanned = tf * con.access_b
         wf = jnp.minimum(wf0, f)
         hit = jnp.where(scanned < rows[_W],
@@ -306,7 +299,7 @@ def _sweep_kernel(dem_ref, lp_ref, np_ref, alive_ref, sin_ref,
                   sout_ref, codes_ref, state_ref, *, t0: int, chunk: int,
                   n_chunks: int, con: _EngineConsts,
                   names: Tuple[str, ...], ix):
-    """One (gain_tile, time_chunk) program of the fused sweep.
+    """One (gain_tile, node_tile, time_chunk) program of the fused sweep.
 
     The tile's stacked state lives in VMEM scratch across the
     sequential time axis; the chunk is an unrolled vector loop with
@@ -314,7 +307,7 @@ def _sweep_kernel(dem_ref, lp_ref, np_ref, alive_ref, sin_ref,
     zero (pure survivor-padding lanes after an in-scan halving gather)
     skips the body entirely and writes deterministic zero codes.
     """
-    ic = pl.program_id(1)
+    ic = pl.program_id(2)
 
     @pl.when(ic == 0)
     def _seed():
@@ -324,13 +317,15 @@ def _sweep_kernel(dem_ref, lp_ref, np_ref, alive_ref, sin_ref,
 
     @pl.when(live)
     def _body():
-        cols = lp_ref[...][:, :, None]                  # (P, TG, 1)
-        rows = np_ref[...]                              # (R, N)
+        lanes = lp_ref[...]                             # (TG, P)
+        cols = tuple(lanes[:, i:i + 1]                  # P x (TG, 1)
+                     for i in range(_N_PARAM_ROWS))
+        rows = np_ref[...]                              # (R, NT)
         wf0 = _warm_fraction0(cols, rows, con)[1] if con.has_cache else None
         stacked = state_ref[...]
         state = tuple(stacked[i] for i in range(len(names)))
         for k in range(chunk):
-            d = dem_ref[k].astype(jnp.float32)          # (N,)
+            d = dem_ref[k:k + 1, :]                     # (1, NT)
             t = ic * chunk + (t0 + k)
             state, codes = _fused_step(state, d, t, cols, rows, wf0,
                                        con, names, ix)
@@ -401,35 +396,43 @@ def _segment(state, demand_seg, lp, np_rows, alive, *, t0: int,
     chunk = _time_chunk(t_seg)
     n_chunks = t_seg // chunk
     tile = min(TILE_GAINS, n_lanes)
+    node_tile = NODE_TILE if n_nodes % NODE_TILE == 0 else n_nodes
     kernel = functools.partial(_sweep_kernel, t0=t0, chunk=chunk,
                                n_chunks=n_chunks, con=con, names=names,
                                ix=ix)
+    # Mosaic tiles the last two block dims by (8, 128) unless a dim is
+    # whole: demand goes in as (n_chunks, chunk, N) so any divisor of
+    # the segment is a legal time chunk, and the per-lane params and
+    # alive mask put lanes on sublanes.
+    dem = demand_seg.reshape(n_chunks, chunk, n_nodes)
     return pl.pallas_call(
         kernel,
-        grid=(n_lanes // tile, n_chunks),
+        grid=(n_lanes // tile, n_nodes // node_tile, n_chunks),
         in_specs=[
-            pl.BlockSpec((chunk, n_nodes), lambda ig, ic: (ic, 0)),
-            pl.BlockSpec((_N_PARAM_ROWS, tile), lambda ig, ic: (0, ig)),
-            pl.BlockSpec((_N_NODE_ROWS, n_nodes), lambda ig, ic: (0, 0)),
-            pl.BlockSpec((1, tile), lambda ig, ic: (0, ig)),
-            pl.BlockSpec((n_state, tile, n_nodes),
-                         lambda ig, ic: (0, ig, 0)),
+            pl.BlockSpec((None, chunk, node_tile),
+                         lambda ig, jn, ic: (ic, 0, jn)),
+            pl.BlockSpec((tile, _N_PARAM_ROWS), lambda ig, jn, ic: (ig, 0)),
+            pl.BlockSpec((_N_NODE_ROWS, node_tile),
+                         lambda ig, jn, ic: (0, jn)),
+            pl.BlockSpec((tile, 1), lambda ig, jn, ic: (ig, 0)),
+            pl.BlockSpec((n_state, tile, node_tile),
+                         lambda ig, jn, ic: (0, ig, jn)),
         ],
         out_specs=[
-            pl.BlockSpec((n_state, tile, n_nodes),
-                         lambda ig, ic: (0, ig, 0)),
-            pl.BlockSpec((chunk, tile, n_nodes),
-                         lambda ig, ic: (ic, ig, 0)),
+            pl.BlockSpec((n_state, tile, node_tile),
+                         lambda ig, jn, ic: (0, ig, jn)),
+            pl.BlockSpec((chunk, tile, node_tile),
+                         lambda ig, jn, ic: (ic, ig, jn)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_state, n_lanes, n_nodes), jnp.float32),
             jax.ShapeDtypeStruct((t_seg, n_lanes, n_nodes), jnp.uint16),
         ],
-        scratch_shapes=[pltpu.VMEM((n_state, tile, n_nodes), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((n_state, tile, node_tile), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend == "interpret",
-    )(demand_seg, lp, np_rows, alive, state)
+    )(dem, lp.T, np_rows, alive.T, state)
 
 
 def _finalize_lanes(state, codes, lp, con: _EngineConsts,
@@ -480,7 +483,6 @@ def _lane_pack(gains: GainSet) -> np.ndarray:
     pack[_U_MAX] = np.asarray(gains.u_max, np.float32)
     pack[_DB] = np.asarray(gains.deadband, np.float32)
     pack[_FF] = np.asarray(gains.feedforward, np.float32)
-    pack[_INV_R0] = np.float32(1.0) / r0
     pack[_THR_OVER] = r0 + np.float32(OVER_R0_EPS)
     pack[_THR_SETTLE] = r0 + np.float32(SETTLE_TOL)
     return pack
@@ -492,12 +494,16 @@ def _node_pack(node_memory, n_nodes: int,
     m = np.broadcast_to(np.asarray(node_memory, np.float64),
                         (n_nodes,)).astype(np.float32)
     pack[_M] = m
-    pack[_INV_M] = np.float32(1.0) / m
     if cache is not None:
-        w = np.float32(cache.working_set_frac) * m
-        pack[_W] = w
-        pack[_INV_W] = np.float32(1.0) / w
+        pack[_W] = np.float32(cache.working_set_frac) * m
     return pack
+
+
+def _reciprocals(lp, np_rows):
+    """Fill the reciprocal rows on device, as the XLA engine divides."""
+    return (lp.at[_INV_R0].set(1.0 / lp[_R0]),
+            np_rows.at[_INV_M].set(1.0 / np_rows[_M])
+            .at[_INV_W].set(1.0 / np_rows[_W]))
 
 
 def _pad_gains(gains: GainSet, multiple: int) -> GainSet:
@@ -558,6 +564,7 @@ def _compiled_pallas_sweep(backend: str, con: _EngineConsts,
                      horizon=int(demand_tn.shape[0]),
                      nodes=int(demand_tn.shape[1]), mode="sweep",
                      spec=spec)
+        lp, np_rows = _reciprocals(lp, np_rows)
         cols = lp[:, :, None]
         d0 = demand_tn[0].astype(jnp.float32)
         state0 = _init_state(cols, np_rows, d0, con, names, ix)
@@ -568,6 +575,21 @@ def _compiled_pallas_sweep(backend: str, con: _EngineConsts,
                                demand_tn.shape[0])
 
     return jax.jit(program)
+
+
+def sweep_program(gains: GainSet, *, backend: str,
+                  cache: Optional[CacheSpec] = None, interval_s: float = 0.1,
+                  occupancy: float = 1.0, precision: str = "f32"):
+    """The jitted program a chunk of ``gains`` runs on ``backend``.
+
+    Takes ``(demand (T, N), node rows (4, N), lane params (10, L),
+    alive (1, L))``; :func:`pallas_sweep_demand` calls it per chunk,
+    and ahead-of-time compiles lower it at described shapes.
+    """
+    plan = plan_specialization(gains, occupancy)
+    con = _engine_consts(plan, cache, interval_s, occupancy, precision)
+    return _compiled_pallas_sweep(backend, con,
+                                  _state_names(con.paper_law, con.has_cache))
 
 
 def pallas_sweep_demand(
@@ -657,10 +679,9 @@ def pallas_sweep_demand(
     chunk = -(-chunk // TILE_GAINS) * TILE_GAINS
     n_real = len(gains)
     gains = _pad_gains(gains, chunk)
-    plan = plan_specialization(gains, occupancy)
-    con = _engine_consts(plan, cache, interval_s, occupancy, precision)
-    names = _state_names(con.paper_law, con.has_cache)
-    fn = _compiled_pallas_sweep(backend, con, names)
+    fn = sweep_program(gains, backend=backend, cache=cache,
+                       interval_s=interval_s, occupancy=occupancy,
+                       precision=precision)
     dem_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
     demand_dev = jnp.asarray(
         np.ascontiguousarray(demand.T, np.float32)).astype(dem_dtype)
@@ -750,6 +771,7 @@ def _compiled_halving(backend: str, con: _EngineConsts,
                      horizon=int(demand_tn.shape[0]),
                      nodes=int(demand_tn.shape[1]), mode="halving",
                      spec=spec)
+        lp, np_rows = _reciprocals(lp, np_rows)
         cols = lp[:, :, None]
         d0 = demand_tn[0].astype(jnp.float32)
         state = _init_state(cols, np_rows, d0, con, names, ix)

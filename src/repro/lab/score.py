@@ -204,7 +204,10 @@ def utilization_codes(utils: Array) -> Array:
     """Quantize utilization ratios onto the fixed streaming-bin grid."""
     lo, _ = QUANT_RANGE
     idx = (jnp.asarray(utils, jnp.float32) - lo) * _QUANT_SCALE
-    return jnp.clip(idx, 0, QUANT_BINS - 1).astype(jnp.uint16)
+    # Through int32: Mosaic has no direct float -> uint16 conversion,
+    # and the in-range truncation is the same either way.
+    return jnp.clip(idx, 0, QUANT_BINS - 1).astype(jnp.int32).astype(
+        jnp.uint16)
 
 
 # Bisection depth of the streaming quantile: 12 levels resolve the

@@ -64,17 +64,11 @@ from typing import NamedTuple, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analysis.runtime import (dispatch_guard, record_trace,
                                 sanitizers_enabled)
 from ._compat import warn_once
-
-try:                                    # jax >= 0.5 exposes it at top level
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ..core.control import ControllerParams, vectorized_step
 from ..core.eviction import policy_model
 from ..core.traces import GiB
@@ -583,24 +577,42 @@ def _compiled_sweep(devices: Tuple, paper_law: bool, unit_occupancy: bool,
             return base(demand_tn, m, *rest, work_sn=work_sn)
     if len(devices) <= 1:
         return jax.jit(fn)
-    gains_specs = (P("gains"),) * 7
+    mapped = jax.shard_map(
+        fn, mesh=sweep_mesh(devices, node_shards),
+        in_specs=(_lead_specs(node_shards, app_graph is not None)
+                  + (P("gains"),) * 7 + (P(), P())),
+        out_specs=P("gains"),
+        check_vma=False)
+    return jax.jit(mapped)
+
+
+def sweep_mesh(devices: Tuple, node_shards: int = 1) -> Mesh:
+    """The 1-D ``("gains",)`` or 2-D ``("gains", "nodes")`` sweep mesh."""
+    if node_shards == 1:
+        return Mesh(np.asarray(devices), ("gains",))
+    grid = np.asarray(devices).reshape(len(devices) // node_shards,
+                                       node_shards)
+    return Mesh(grid, ("gains", "nodes"))
+
+
+def _lead_specs(node_shards: int, with_work: bool) -> Tuple:
+    """Partition specs of demand, node memory and (AppGraph) work."""
     node_p = P(None) if node_shards == 1 else P("nodes")
     demand_p = P(None, None) if node_shards == 1 else P(None, "nodes")
-    lead_specs = (demand_p, node_p)
-    if app_graph is not None:
-        lead_specs = lead_specs + (demand_p,)          # work_sn (S+1, N)
-    if node_shards == 1:
-        mesh = Mesh(np.asarray(devices), ("gains",))
-    else:
-        grid = np.asarray(devices).reshape(
-            len(devices) // node_shards, node_shards)
-        mesh = Mesh(grid, ("gains", "nodes"))
-    mapped = _shard_map(
-        fn, mesh=mesh,
-        in_specs=lead_specs + gains_specs + (P(), P()),
-        out_specs=P("gains"),
-        check_rep=False)
-    return jax.jit(mapped)
+    lead = (demand_p, node_p)
+    return lead + (demand_p,) if with_work else lead   # work_sn (S+1, N)
+
+
+def _stager(devices: Tuple, node_shards: int):
+    """``stage(array, spec)``: put an operand where the program reads it.
+
+    On a mesh each operand goes to its own ``NamedSharding`` once, so no
+    chunk call reshards it; one device keeps the default placement.
+    """
+    if len(devices) <= 1:
+        return lambda x, spec: jnp.asarray(x)
+    mesh = sweep_mesh(devices, node_shards)
+    return lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
 
 
 def resolve_devices(devices: Union[None, int, Sequence] = None) -> Tuple:
@@ -796,32 +808,25 @@ def sweep_demand(
     plan = plan_specialization(gains, occupancy)
     fn = _compiled_sweep(devs, plan.paper_law, plan.unit_occupancy,
                          plan.static_bounds, cache, node_shards, app_graph)
-    # Stage every operand device-side (f32) exactly once.  The gain
-    # columns used to go up as numpy float64 slices -- a silent
-    # H2D transfer + cast per chunk per array -- so chunks are now
-    # sliced on device and the loop body is transfer-free, which
-    # dispatch_guard() (PLANECHECK_SANITIZERS=1) enforces with
-    # jax.transfer_guard("disallow").
-    demand_dev = jnp.asarray(demand_tn)
-    m_dev = jnp.asarray(m)
-    lead = (demand_dev, m_dev)
+    # Stage every operand device-side (f32) exactly once, before the
+    # guarded dispatch loop, so the loop body is transfer-free (which
+    # dispatch_guard() enforces under PLANECHECK_SANITIZERS=1).
+    stage = _stager(devs, node_shards)
+    lead_p = _lead_specs(node_shards, app_graph is not None)
+    lead = (stage(demand_tn, lead_p[0]), stage(m, lead_p[1]))
     if app_graph is not None:
         # The (S+1, N) work matrix compiles against the *global* fleet
         # (task round-robin and slow-node skew need true node indices)
         # and is staged once like demand; node sharding splits its
         # column axis the same way.
-        lead = lead + (jnp.asarray(
-            compile_graph(app_graph, n_nodes).work_gib),)
-    gain_dev = [jnp.asarray(getattr(gains, f.name), jnp.float32)
-                for f in dataclasses.fields(GainSet)]
-    iv = jnp.asarray(np.float32(interval_s))
-    occ = jnp.asarray(np.float32(occupancy))
-
-    # Device-side chunk slices, materialized before the guard (each
-    # distinct slice bound compiles its own tiny getitem executable,
-    # whose constants would otherwise transfer inside the guard).
-    cols_per_chunk = [[a[lo:lo + chunk] for a in gain_dev]
-                     for lo in range(0, len(gains), chunk)]
+        lead = lead + (stage(compile_graph(app_graph, n_nodes).work_gib,
+                             lead_p[2]),)
+    gain_cols = [np.asarray(getattr(gains, f.name), np.float32)
+                 for f in dataclasses.fields(GainSet)]
+    iv = stage(np.float32(interval_s), P())
+    occ = stage(np.float32(occupancy), P())
+    cols_per_chunk = [[stage(a[lo:lo + chunk], P("gains")) for a in gain_cols]
+                      for lo in range(0, len(gains), chunk)]
     if sanitizers_enabled():
         # Compile (and its constant transfers) happen outside the guard;
         # the guarded loop below then replays only cached executables.
@@ -835,6 +840,44 @@ def sweep_demand(
     return FleetStats(*(np.concatenate([getattr(c, f)
                                         for c in chunks])[:n_real]
                         for f in FleetStats._fields))
+
+
+def oracle_history(demand: np.ndarray, m, params: ControllerParams,
+                   occupancy: float = 1.0) -> Tuple[np.ndarray, np.ndarray]:
+    """Float64 numpy replay of Eq. 1 on a saturated store: the oracle.
+
+    Independent of the engines (scalar law, no precomputed reciprocals,
+    no streaming): returns the dense ``(T, N)`` utilization history
+    ``v / M`` and the granted capacity after each interval (bytes) for
+    one gain point, which tests and the chip smoke reduce to the stats
+    :func:`sweep_demand` streams.
+    """
+    demand = np.asarray(demand, np.float64)
+    m = np.broadcast_to(np.asarray(m, np.float64), (demand.shape[0],))
+    n, t = demand.shape
+    u = np.full(n, params.u_max, np.float64)
+    v_prev = None
+    utils = np.empty((t, n))
+    caps = np.empty((t, n))
+    for i in range(t):
+        v = demand[:, i] + occupancy * u
+        v_eff = v.copy()
+        if params.feedforward > 0.0 and v_prev is not None:
+            v_eff = v + params.feedforward * (v - v_prev)
+        r = v_eff / m
+        err = r - params.r0
+        lam = np.where(
+            err < 0,
+            params.lam if params.lam_grant is None else params.lam_grant,
+            params.lam)
+        u_next = u - lam * v_eff * err / params.r0
+        if params.deadband > 0.0:
+            u_next = np.where(np.abs(err) <= params.deadband, u, u_next)
+        u = np.clip(u_next, params.u_min, params.u_max)
+        utils[i] = v / m
+        caps[i] = u
+        v_prev = v
+    return utils, caps
 
 
 @dataclasses.dataclass

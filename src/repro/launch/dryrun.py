@@ -26,6 +26,10 @@ import sys
 import time
 import traceback
 
+# The chip the production meshes describe (TPU v5e pods); the dry-run
+# compiles on forced host devices, so the roofline names its target.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
              settings_override: dict = None, tag: str = "") -> dict:
@@ -34,7 +38,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
     from ..configs import get_config, get_shape
     from ..launch.cells import CellSettings, build_cell, cell_settings
     from ..launch.mesh import activate_mesh, describe, make_production_mesh
-    from ..roofline.analysis import analyze_compiled
+    from ..roofline import analyze_compiled, chip_peaks
 
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
@@ -55,7 +59,9 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: str,
 
     n_chips = int(mesh.devices.size)
     hlo_text = compiled.as_text()
-    result = analyze_compiled(compiled, desc, n_chips, hlo_text=hlo_text)
+    result = analyze_compiled(compiled, desc, n_chips,
+                              chip_peaks(TARGET_DEVICE_KIND),
+                              hlo_text=hlo_text)
     result["timing"] = {"lower_s": round(t_lower, 1),
                         "compile_s": round(t_compile, 1)}
 

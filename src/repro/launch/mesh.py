@@ -14,41 +14,41 @@ Mesh semantics (DESIGN.md §4):
 
 from __future__ import annotations
 
-import contextlib
-from typing import Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def activate_mesh(mesh):
     """Context manager making ``mesh`` the ambient mesh.
 
-    ``jax.set_mesh`` only exists on newer jax (where it both sets the
-    mesh and returns a context manager restoring the previous one); on
-    older releases (<= 0.4.x) entering the ``Mesh`` context manager
-    provides the same ambient-mesh semantics (bare ``PartitionSpec``
-    sharding constraints resolve against it) for the duration of the
-    block.  Either way the mesh is only ambient inside the ``with``.
+    ``jax.set_mesh`` sets the mesh and returns a context manager that
+    restores the previous one, so the mesh is only ambient inside the
+    ``with``.
     """
-    if hasattr(jax, "set_mesh"):
-        ctx = jax.set_mesh(mesh)
-        return ctx if ctx is not None else contextlib.nullcontext(mesh)
-    return mesh
+    return jax.set_mesh(mesh)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: Sequence[int], axes: Sequence[str]):
-    """Arbitrary mesh (elastic re-mesh path, tests)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    """Arbitrary mesh (elastic re-mesh path, tests).
+
+    Axes are ``Auto``: the model stack places arrays through sharding
+    constraints and jit shardings that the partitioner propagates, not
+    through sharding-typed arrays.
+    """
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def single_device_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def describe(mesh) -> dict:

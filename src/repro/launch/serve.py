@@ -17,12 +17,16 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional, Sequence
 
 import jax
 import numpy as np
 
+from .compile_cache import enable_compile_cache
 
-def main() -> None:
+
+def main(argv: Optional[Sequence[str]] = None):
+    """Run the CLI on ``argv`` (default ``sys.argv``); returns the engine."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-1b-smoke")
     ap.add_argument("--requests", type=int, default=12)
@@ -39,7 +43,8 @@ def main() -> None:
                     help="supervised retune: restart a crashed tuning "
                          "round up to N times with backoff")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     from ..configs import get_config
     from ..configs.dynims import hbm_pool_params
@@ -76,7 +81,7 @@ def main() -> None:
     stats = engine.stats()
     toks = sum(len(r.output) for r in finished.values())
     print(f"served {len(finished)} requests, {toks} tokens in {dt:.1f}s "
-          f"({toks/dt:.1f} tok/s on CPU)")
+          f"({toks/dt:.1f} tok/s on {jax.devices()[0].device_kind})")
     print("engine:", stats)
 
     if args.retune:
@@ -101,6 +106,7 @@ def main() -> None:
         wave2 = engine.run_until_drained()
         print(f"   second wave under epoch {plane.epoch}: served "
               f"{len(wave2)} requests")
+    return engine
 
 
 if __name__ == "__main__":

@@ -27,6 +27,8 @@ import tempfile
 import jax
 import numpy as np
 
+from .compile_cache import enable_compile_cache
+
 
 def main() -> None:
     ap = argparse.ArgumentParser()
@@ -43,6 +45,7 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     from ..configs import get_config
     from ..configs.dynims import host_cache_params

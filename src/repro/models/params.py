@@ -156,24 +156,8 @@ def shard_act(x: jax.Array, spec: P) -> jax.Array:
 
 
 def _ambient_axis_names() -> tuple:
-    """Axis names of whichever ambient mesh is active, if any.
-
-    Newer jax exposes the abstract mesh set by ``jax.set_mesh``; on
-    older releases the ``with mesh:`` context manager populates the
-    legacy thread-resources env instead -- check both so activation
-    pinning works under either idiom.
-    """
-    from jax._src import mesh as mesh_lib
-    try:
-        names = tuple(mesh_lib.get_abstract_mesh().axis_names)
-        if names:
-            return names
-    except Exception:
-        pass
-    try:
-        return tuple(mesh_lib.thread_resources.env.physical_mesh.axis_names)
-    except Exception:
-        return ()
+    """Axis names of the ambient mesh set by ``jax.set_mesh``, if any."""
+    return tuple(jax.sharding.get_abstract_mesh().axis_names)
 
 
 def axes_for(mesh) -> Axes:

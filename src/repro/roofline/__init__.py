@@ -1,8 +1,8 @@
 """Roofline analysis over compiled dry-run artifacts."""
 
 from .analysis import analyze_compiled, roofline_terms
-from .constants import HBM_BW, ICI_BW, PEAK_FLOPS
+from .constants import CHIPS, ChipPeaks, chip_peaks
 from .hlo import parse_collectives
 
-__all__ = ["HBM_BW", "ICI_BW", "PEAK_FLOPS", "analyze_compiled",
+__all__ = ["CHIPS", "ChipPeaks", "analyze_compiled", "chip_peaks",
            "parse_collectives", "roofline_terms"]

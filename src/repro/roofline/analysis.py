@@ -16,7 +16,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional
 
-from .constants import HBM_BW, ICI_BW, PEAK_FLOPS
+from .constants import ChipPeaks
 from .hlo import parse_collectives
 
 
@@ -30,11 +30,10 @@ def model_flops(n_params: int, n_active: int, tokens: int,
 
 def roofline_terms(*, hlo_flops_per_chip: float, hlo_bytes_per_chip: float,
                    collective_bytes_per_chip: float,
-                   peak_flops: float = PEAK_FLOPS, hbm_bw: float = HBM_BW,
-                   ici_bw: float = ICI_BW) -> Dict[str, float]:
-    compute = hlo_flops_per_chip / peak_flops
-    memory = hlo_bytes_per_chip / hbm_bw
-    collective = collective_bytes_per_chip / ici_bw
+                   chip: ChipPeaks) -> Dict[str, float]:
+    compute = hlo_flops_per_chip / chip.peak_flops
+    memory = hlo_bytes_per_chip / chip.hbm_bw
+    collective = collective_bytes_per_chip / chip.ici_bw
     terms = {"compute_s": compute, "memory_s": memory,
              "collective_s": collective}
     dominant = max(terms, key=terms.get)
@@ -48,9 +47,9 @@ def roofline_terms(*, hlo_flops_per_chip: float, hlo_bytes_per_chip: float,
     }
 
 
-def analyze_compiled(compiled, desc: dict, n_chips: int,
+def analyze_compiled(compiled, desc: dict, n_chips: int, chip: ChipPeaks,
                      hlo_text: Optional[str] = None) -> dict:
-    """Extract the full §Roofline row for one compiled cell.
+    """Extract the full §Roofline row for one compiled cell on ``chip``.
 
     Primary accounting is the trip-count-aware HLO cost model
     (roofline/hlo_cost.py); the backend's ``cost_analysis()`` is kept in
@@ -97,6 +96,7 @@ def analyze_compiled(compiled, desc: dict, n_chips: int,
         hlo_flops_per_chip=flops,
         hlo_bytes_per_chip=nbytes,
         collective_bytes_per_chip=coll["total_bytes"],
+        chip=chip,
     )
     mf = model_flops(desc["n_params"], desc.get("n_active_params", 0),
                      desc["tokens"], desc["kind"])
@@ -116,7 +116,7 @@ def analyze_compiled(compiled, desc: dict, n_chips: int,
         "useful_flops_ratio": (mf_per_chip / flops) if flops else 0.0,
         "step_time_bound_s": terms["bound_s"],
         "model_flops_utilization_bound": (
-            mf_per_chip / PEAK_FLOPS / terms["bound_s"]
+            mf_per_chip / chip.peak_flops / terms["bound_s"]
             if terms["bound_s"] > 0 else 0.0),
     }
 

@@ -102,6 +102,7 @@ class ServingEngine:
                 node, monitor,
                 stores=(StoreSpec(self.pool, self.pool.total_blocks
                                   * self.pool.block_bytes),))
+        self.monitor = monitor
         self.queue: List[Request] = []
         self.finished: Dict[int, Request] = {}
         self.slots = [_Slot() for _ in range(cfg.max_batch)]

@@ -3,9 +3,8 @@
 Four pins hold the PR-9 engine in place:
 
 * **cross-engine parity** -- ``engine="pallas"`` must reproduce
-  ``engine="xla"`` stat for stat on the registry scenarios (bit-level
-  on the saturated-store path; the cache path differs only through
-  ``_fast_pow`` on the hit curve, bounded well under the 1e-4 budget);
+  ``engine="xla"`` stat for stat on the registry scenarios, bit for
+  bit on the saturated-store and the cache paths alike;
 * **lowering parity** -- the production CPU scan and the true
   ``pallas_call`` interpret-mode kernel share ``_fused_step``, so they
   must agree bit for bit, deterministically across runs;
@@ -28,16 +27,12 @@ from repro.core.traces import GiB
 from repro.fleet import fleet_sweep_demand
 from repro.lab import (FleetStats, GainSet, get_scenario, grid_gains,
                        halving_tune, run_sweep, sweep_demand, tune_gains)
+from repro.lab import pallas_sweep
 from repro.lab._compat import reset_warnings
 from repro.lab.pallas_sweep import (halving_schedule, halving_sweep,
                                     pallas_sweep_demand)
 
 P = paper_controller_params()
-
-# The one stat whose pallas spelling is _fast_pow (exp2/log2) instead
-# of XLA's pow lowering; everything else must match bit for bit on the
-# cache path too.
-FAST_POW_FIELDS = ("hit_ratio", "app_runtime", "app_slowdown")
 
 
 def _scenario(name, n_nodes, n_intervals, cache=True, seed=3):
@@ -92,7 +87,7 @@ def test_engine_parity_saturated_store(name):
 
 
 def test_engine_parity_cacheloop():
-    """CacheLoop scenario: only the _fast_pow spelling may differ."""
+    """CacheLoop scenario: the hit curve's pow included, bit for bit."""
     demand, m, cache = _scenario("spark-iterative-cache", 12, 150)
     assert cache is not None
     gains = _gains()
@@ -101,14 +96,9 @@ def test_engine_parity_cacheloop():
     got = sweep_demand(demand, gains, engine="pallas", **kw)
     da, db = _stats_dict(ref), _stats_dict(got)
     for field in FleetStats._fields:
-        if field in FAST_POW_FIELDS:
-            np.testing.assert_allclose(
-                da[field], db[field], rtol=1e-4,
-                err_msg=f"cache path: {field} outside the parity budget")
-        else:
-            np.testing.assert_array_equal(
-                da[field], db[field],
-                err_msg=f"cache path: {field} not bit-identical")
+        np.testing.assert_array_equal(
+            da[field], db[field],
+            err_msg=f"cache path: {field} not bit-identical")
 
 
 def test_run_sweep_engine_kwarg_roundtrip():
@@ -124,11 +114,16 @@ def test_run_sweep_engine_kwarg_roundtrip():
 # Lowering parity + determinism
 # ---------------------------------------------------------------------------
 
-def test_scan_matches_interpret_kernel():
+@pytest.mark.parametrize("n_nodes,n_intervals,n_side", [
+    (8, 48, 2),
+    # two gain tiles x two node tiles: the Mosaic grid's block indexing
+    (2 * pallas_sweep.NODE_TILE, 40, 4)])
+def test_scan_matches_interpret_kernel(n_nodes, n_intervals, n_side):
     """The production scan and the pallas_call interpret kernel share
     one jaxpr; both lowerings must agree bit for bit."""
-    demand, m, cache = _scenario("spark-iterative-cache", 8, 48, seed=1)
-    gains = _gains(2, 2)
+    demand, m, cache = _scenario("spark-iterative-cache", n_nodes,
+                                 n_intervals, seed=1)
+    gains = _gains(n_side, n_side)
     kw = dict(node_memory=m, interval_s=P.interval_s, cache=cache)
     a = pallas_sweep_demand(demand, gains, **kw)
     b = pallas_sweep_demand(demand, gains, force_interpret=True, **kw)

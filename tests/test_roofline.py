@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.roofline import PEAK_FLOPS, parse_collectives, roofline_terms
+from repro.roofline import chip_peaks, parse_collectives, roofline_terms
 from repro.roofline.analysis import model_flops
 from repro.roofline.hlo_cost import hlo_cost
 
@@ -43,10 +43,7 @@ def test_backend_cost_analysis_is_wrong_on_loops():
     ws = jax.ShapeDtypeStruct((8, 1024, 1024), jnp.float32)
     c = jax.jit(jax.value_and_grad(_chain(8, False),
                                    argnums=(0, 1))).lower(x, ws).compile()
-    analysis = c.cost_analysis()
-    if isinstance(analysis, list):       # jax <= 0.4.x: one dict per device
-        analysis = analysis[0]
-    backend = analysis["flops"]
+    backend = c.cost_analysis()["flops"]
     ours = hlo_cost(c.as_text())["flops"]
     assert ours >= 3 * backend
 
@@ -64,12 +61,19 @@ def test_remat_reduces_bytes():
 def test_roofline_terms_and_dominance():
     t = roofline_terms(hlo_flops_per_chip=197e12,       # exactly 1 s
                        hlo_bytes_per_chip=819e9 / 2,    # 0.5 s
-                       collective_bytes_per_chip=50e9 / 4)
+                       collective_bytes_per_chip=50e9 / 4,
+                       chip=chip_peaks("TPU v5 lite"))
     assert t["compute_s"] == pytest.approx(1.0)
     assert t["memory_s"] == pytest.approx(0.5)
     assert t["collective_s"] == pytest.approx(0.25)
     assert t["dominant"] == "compute"
     assert t["bound_s"] == pytest.approx(1.0)
+
+
+def test_chip_peaks_keyed_by_device_kind():
+    assert chip_peaks("TPU v5 lite").hbm_bw == 819e9
+    with pytest.raises(KeyError, match="no published peaks"):
+        chip_peaks("cpu")
 
 
 def test_model_flops_conventions():
@@ -84,7 +88,7 @@ def test_parse_collectives_finds_psum():
     def f(a):
         return jax.lax.psum(a, "x")
 
-    from jax.experimental.shard_map import shard_map
+    shard_map = jax.shard_map
     from jax.sharding import PartitionSpec as P
     fn = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P()))
     c = fn.lower(jax.ShapeDtypeStruct((16, 64), jnp.float32)).compile()

@@ -113,3 +113,32 @@ def test_admission_respects_pool_budget(small_model):
     eng.step()
     assert sum(not s.free for s in eng.slots) == 1
     assert len(eng.queue) == 2
+
+
+class _FakeDevice:
+    def __init__(self, platform, stats):
+        self.platform, self.id, self._stats = platform, 0, stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_device_monitor_reads_accelerator_stats():
+    from repro.core.monitor import DeviceMemoryMonitor
+    dev = _FakeDevice("tpu", {"bytes_limit": 1000, "bytes_in_use": 250})
+    s = DeviceMemoryMonitor(dev, assumed_total=1.0).sample()
+    assert (s.total, s.used) == (1000.0, 250.0)
+
+
+def test_device_monitor_assumes_totals_only_on_cpu():
+    from repro.core.monitor import DeviceMemoryMonitor, MonitorFault
+    s = DeviceMemoryMonitor(_FakeDevice("cpu", None),
+                            assumed_total=64.0).sample()
+    assert (s.total, s.used) == (64.0, 0.0)
+    with pytest.raises(ValueError, match="bytes_limit"):
+        DeviceMemoryMonitor(_FakeDevice("tpu", {"bytes_in_use": 1}))
+    dev = _FakeDevice("tpu", {"bytes_limit": 10, "bytes_in_use": 1})
+    mon = DeviceMemoryMonitor(dev)
+    dev._stats = {}
+    with pytest.raises(MonitorFault):
+        mon.sample()

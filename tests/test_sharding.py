@@ -47,7 +47,7 @@ import json, sys
 import jax
 from repro.launch.cells import CellSettings, build_cell
 from repro.launch.mesh import activate_mesh, make_mesh
-from repro.roofline.analysis import analyze_compiled
+from repro.roofline import analyze_compiled, chip_peaks
 
 mesh = make_mesh((4, 2), ("data", "model"))
 out = {}
@@ -66,7 +66,7 @@ for arch, shape in [("llama3.2-1b-smoke", "train_4k"),
                                           settings=CellSettings(microbatches=2 if shp.kind == "train" else 1,
                                                                 attn_impl="dense"))
             compiled = jax.jit(fn).lower(*inputs).compile()
-        r = analyze_compiled(compiled, desc, 8)
+        r = analyze_compiled(compiled, desc, 8, chip_peaks("TPU v5 lite"))
         out[shape] = {"flops": r["hlo_flops_per_chip"],
                       "dominant": r["roofline"]["dominant"]}
     finally:

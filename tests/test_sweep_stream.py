@@ -18,39 +18,12 @@ from repro.lab import (FleetStats, GainSet, QUANT_BINS, QUANT_RANGE,
                        get_scenario, grid_gains, halving_tune,
                        quantile_from_codes, run_sweep, sweep_demand,
                        tune_gains, tune_portfolio, utilization_codes)
+from repro.lab.sweep import oracle_history
 
 # Worst-case error of the streaming p99: 12-level bisection bracket
 # (2^-13 of the QUANT_RANGE span) plus half a bin.  The satellite
 # acceptance bound is 0.005; the implementation is ~10x tighter.
 P99_TOL = 0.005
-
-
-def oracle_utils(demand, m, params, occupancy=1.0):
-    """Dense (T, N) utilization history from a float64 reference loop."""
-    demand = np.asarray(demand, np.float64)
-    m = np.broadcast_to(np.asarray(m, np.float64), (demand.shape[0],))
-    n, t = demand.shape
-    u = np.full(n, params.u_max, np.float64)
-    v_prev = None
-    utils = np.empty((t, n))
-    for i in range(t):
-        v = demand[:, i] + occupancy * u
-        v_eff = v.copy()
-        if params.feedforward > 0.0 and v_prev is not None:
-            v_eff = v + params.feedforward * (v - v_prev)
-        r = v_eff / m
-        err = r - params.r0
-        lam = np.where(
-            err < 0,
-            params.lam if params.lam_grant is None else params.lam_grant,
-            params.lam)
-        u_next = u - lam * v_eff * err / params.r0
-        if params.deadband > 0.0:
-            u_next = np.where(np.abs(err) <= params.deadband, u, u_next)
-        u = np.clip(u_next, params.u_min, params.u_max)
-        utils[i] = v / m
-        v_prev = v
-    return utils
 
 
 SCENARIO_SHRINKS = {
@@ -71,7 +44,7 @@ def test_streaming_quantile_accuracy_vs_numpy(name):
     stats = sweep_demand(demand, GainSet.from_params(p), node_memory=m,
                          interval_s=spec.interval_s,
                          occupancy=spec.occupancy)
-    ref = oracle_utils(demand, m, p, occupancy=spec.occupancy)
+    ref, _ = oracle_history(demand, m, p, occupancy=spec.occupancy)
     assert abs(float(stats.p99_utilization[0])
                - np.quantile(ref, 0.99)) <= P99_TOL
     # the streamed companions stay pinned to the dense history too
