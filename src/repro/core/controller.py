@@ -34,6 +34,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
+from ..analysis.runtime import span
 from .bus import MessageBus
 from .control import ControllerParams, Signal, control_step
 from .store import EvictionReport, StoreRegistry
@@ -216,8 +217,11 @@ class DynIMSController:
         """Backend interface: actions produced since the last flush.
 
         Complete only when constructed with ``track_fresh=True`` (as
-        :class:`~repro.core.plane.MemoryPlane` does)."""
-        return self._history.drain()
+        :class:`~repro.core.plane.MemoryPlane` does).  The law ran as
+        each observation arrived; this drain is the tick's
+        ``plane.tick.law`` span."""
+        with span("plane.tick.law"):
+            return self._history.drain()
 
     def step(self, agg: AggregatedMetrics) -> Optional[ControlAction]:
         """Run Eq. 1 for one node from one aggregated observation."""

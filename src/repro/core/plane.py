@@ -47,6 +47,7 @@ dropping a tick, and every action is stamped with the parameter epoch.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
 import math
@@ -61,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..analysis.runtime import record_trace
+from ..analysis.runtime import count, record_trace, span, span_log
 from .bus import MessageBus
 from .control import ControllerParams, Signal, vectorized_step
 from .controller import (ActionHistory, CONTROL_TOPIC, ControlAction,
@@ -75,6 +76,9 @@ BACKENDS = ("array", "scalar")
 
 #: Default ring-buffer capacity (control intervals) of a TraceRecorder.
 DEFAULT_TRACE_CAPACITY = 4096
+
+#: The phases of one tick, each timed by the span ``plane.tick.<phase>``.
+TICK_PHASES = ("sample", "publish", "law", "actuate", "record")
 
 
 # ---------------------------------------------------------------------------
@@ -686,7 +690,7 @@ def make_fused_step(params: ControllerParams):
     """
     ff = params.feedforward
 
-    def fused(u, v, v_prev, has_prev, mask, m, u_min, u_max):
+    def plane_fused_law(u, v, v_prev, has_prev, mask, m, u_min, u_max):
         # Trace-time recompile counter: fires once per XLA compile, so
         # the sanitizer fixtures can assert the fleet shape is stable.
         record_trace("plane.fused_step", nodes=int(u.shape[0]))
@@ -699,7 +703,7 @@ def make_fused_step(params: ControllerParams):
             deadband=params.deadband, v_prev=vp, feedforward=ff)
         return jnp.where(mask, u_next, u)
 
-    return jax.jit(fused)
+    return jax.jit(plane_fused_law)
 
 
 _CAPACITY_FIELDS = ("total_memory", "u_min", "u_max")
@@ -859,49 +863,56 @@ class ArrayController:
             self._pending[agg.node] = agg
 
     def flush(self) -> List[ControlAction]:
-        """One control interval: fused decide, then per-node actuation."""
-        with self._lock:
-            pending, self._pending = self._pending, {}
-            observed = sorted(
-                (self._index[n], n, a) for n, a in pending.items()
-                if n in self._index)
-            if not observed:
-                return []
-            n_nodes = self._u.size
-            mask = np.zeros(n_nodes, bool)
-            v = self._v_prev.copy()      # placeholder; masked out below
-            for i, _, agg in observed:
-                mask[i] = True
-                v[i] = self.signal.pick(agg)
-                if agg.total > 0 and agg.total != self._m[i]:
-                    self._m[i] = agg.total
-            u_next = np.asarray(self._fused(
-                jnp.asarray(self._u, jnp.float32),
-                jnp.asarray(v, jnp.float32),
-                jnp.asarray(self._v_prev, jnp.float32),
-                jnp.asarray(self._has_prev),
-                jnp.asarray(mask),
-                jnp.asarray(self._m, jnp.float32),
-                jnp.asarray(self._u_min, jnp.float32),
-                jnp.asarray(self._u_max, jnp.float32),
-            ), np.float64)
-            actions: List[ControlAction] = []
-            for i, name, agg in observed:
-                reports = self._registries[i].apply_capacity(u_next[i])
-                action = ControlAction(
-                    node=name, timestamp=agg.timestamp,
-                    u_prev=float(self._u[i]), u_next=float(u_next[i]),
-                    utilization=v[i] / agg.total if agg.total else 0.0,
-                    reports=reports, epoch=self._epoch)
-                actions.append(action)
-                self._history.append(action)
-                self._u[i] = u_next[i]
-                self._v_prev[i] = v[i]
-                self._has_prev[i] = True
-        if self._bus is not None:
-            for action in actions:
-                self._bus.publish(CONTROL_TOPIC, action)
-        return actions
+        """One control interval: fused decide, then per-node actuation.
+
+        The decision runs under the span ``plane.tick.law``, the
+        actuation and the CONTROL_TOPIC publishes under
+        ``plane.tick.actuate``."""
+        with contextlib.ExitStack() as actuating:
+            with self._lock:
+                pending, self._pending = self._pending, {}
+                observed = sorted(
+                    (self._index[n], n, a) for n, a in pending.items()
+                    if n in self._index)
+                if not observed:
+                    return []
+                with span("plane.tick.law"):
+                    n_nodes = self._u.size
+                    mask = np.zeros(n_nodes, bool)
+                    v = self._v_prev.copy()  # placeholder; masked out
+                    for i, _, agg in observed:
+                        mask[i] = True
+                        v[i] = self.signal.pick(agg)
+                        if agg.total > 0 and agg.total != self._m[i]:
+                            self._m[i] = agg.total
+                    u_next = np.asarray(self._fused(
+                        jnp.asarray(self._u, jnp.float32),
+                        jnp.asarray(v, jnp.float32),
+                        jnp.asarray(self._v_prev, jnp.float32),
+                        jnp.asarray(self._has_prev),
+                        jnp.asarray(mask),
+                        jnp.asarray(self._m, jnp.float32),
+                        jnp.asarray(self._u_min, jnp.float32),
+                        jnp.asarray(self._u_max, jnp.float32),
+                    ), np.float64)
+                actuating.enter_context(span("plane.tick.actuate"))
+                actions: List[ControlAction] = []
+                for i, name, agg in observed:
+                    reports = self._registries[i].apply_capacity(u_next[i])
+                    action = ControlAction(
+                        node=name, timestamp=agg.timestamp,
+                        u_prev=float(self._u[i]), u_next=float(u_next[i]),
+                        utilization=v[i] / agg.total if agg.total else 0.0,
+                        reports=reports, epoch=self._epoch)
+                    actions.append(action)
+                    self._history.append(action)
+                    self._u[i] = u_next[i]
+                    self._v_prev[i] = v[i]
+                    self._has_prev[i] = True
+            if self._bus is not None:
+                for action in actions:
+                    self._bus.publish(CONTROL_TOPIC, action)
+            return actions
 
     def squeeze(self, node: str, factor: float) -> bool:
         """Transient capacity clamp (see DynIMSController.squeeze)."""
@@ -927,6 +938,20 @@ class ArrayController:
             self._v_prev[i] = 0.0
             self._has_prev[i] = False
             return True
+
+
+def _phase_times(since_ns: int) -> str:
+    """``sample 0.201s, publish ...``: this thread's tick phase spans
+    opened at or after ``since_ns``."""
+    me = threading.get_ident()
+    parts = []
+    for phase in TICK_PHASES:
+        recs = [r for r in span_log(f"plane.tick.{phase}")
+                if r.thread == me and r.start_ns >= since_ns]
+        if recs:
+            parts.append(
+                f"{phase} {(recs[-1].end_ns - recs[-1].start_ns) * 1e-9:.3f}s")
+    return ", ".join(parts)
 
 
 # ---------------------------------------------------------------------------
@@ -1278,24 +1303,32 @@ class MemoryPlane:
         health state machine first -- a faulty monitor degrades that
         node (holdover, then fail-static quarantine) instead of feeding
         the law garbage or taking the interval down with an exception.
+        Each phase of :data:`TICK_PHASES` runs under the span
+        ``plane.tick.<phase>``; a missed deadline logs their times.
         """
         t0 = time.monotonic()
+        t0_ns = time.perf_counter_ns()
         with self._tick_lock:
             with self._lock:
                 monitors = dict(self._monitors)
                 registries = dict(self._registries)
             tick = self._tick_index()
             samples: Dict[str, MemorySample] = {}
-            for name, mon in monitors.items():
-                s = self._observe_node(name, mon, registries.get(name),
-                                       tick)
-                if s is not None:
-                    samples[name] = s
-            for sample in samples.values():
-                self.bus.publish(RAW_TOPIC, sample)
+            with span("plane.tick.sample"):
+                for name, mon in monitors.items():
+                    s = self._observe_node(name, mon, registries.get(name),
+                                           tick)
+                    if s is not None:
+                        samples[name] = s
+            count("plane.tick.nodes_sampled", len(monitors))
+            with span("plane.tick.publish"):
+                for sample in samples.values():
+                    self.bus.publish(RAW_TOPIC, sample)
             actions = self.controller.flush()
-            if self.recorder is not None:
-                self.recorder.record(samples, actions)
+            count("plane.tick.actions", len(actions))
+            with span("plane.tick.record"):
+                if self.recorder is not None:
+                    self.recorder.record(samples, actions)
             deadline = self.health_policy.tick_deadline_s
             elapsed = time.monotonic() - t0
             missed = deadline is not None and elapsed > deadline
@@ -1308,7 +1341,7 @@ class MemoryPlane:
                     kind="tick-deadline", node=None, tick=tick,
                     timestamp=time.time(),
                     detail=f"interval took {elapsed:.3f}s "
-                           f"> {deadline}s"))
+                           f"> {deadline}s ({_phase_times(t0_ns)})"))
             return actions
 
     def run(self, duration_s: Optional[float] = None) -> None:

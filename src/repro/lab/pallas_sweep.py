@@ -71,8 +71,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..analysis.runtime import (dispatch_guard, record_trace,
-                                sanitizers_enabled)
+from ..analysis.runtime import (count, dispatch_guard, record_trace,
+                                sanitizers_enabled, span)
 from ..core.control import vectorized_step
 from ..core.eviction import policy_model
 from ..core.traces import GiB
@@ -81,7 +81,7 @@ from .scenarios import CacheSpec
 from .score import (FleetStats, OVER_R0_EPS, SETTLE_TOL, default_score,
                     finalize_fleet_stats, hpl_slowdown_curve, kahan_add,
                     quantile_from_codes, utilization_codes)
-from .sweep import (GainSet, _resolve_chunk, paper_law_mask,
+from .sweep import (GainSet, _resolve_chunk, _stitch, paper_law_mask,
                     plan_specialization, resolve_devices)
 
 # Gain lanes per kernel tile (the sublane axis of the VPU's 8x128
@@ -432,6 +432,7 @@ def _segment(state, demand_seg, lp, np_rows, alive, *, t0: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=backend == "interpret",
+        name="lab_sweep_kernel",
     )(dem, lp.T, np_rows, alive.T, state)
 
 
@@ -554,7 +555,7 @@ def _compiled_pallas_sweep(backend: str, con: _EngineConsts,
     ix = {n: i for i, n in enumerate(names)}
     spec = _spec_digest("sweep", backend, con, names)
 
-    def program(demand_tn, np_rows, lp, alive):
+    def lab_pallas_chunk(demand_tn, np_rows, lp, alive):
         # Trace-time recompile counter (see lab.sweep._chunk_stats):
         # shapes from the operands, everything else -- backend, the
         # full consts dataclass (cache knobs, interval, precision),
@@ -574,7 +575,7 @@ def _compiled_pallas_sweep(backend: str, con: _EngineConsts,
         return _finalize_lanes(state, codes, lp, con, names, ix,
                                demand_tn.shape[0])
 
-    return jax.jit(program)
+    return jax.jit(lab_pallas_chunk)
 
 
 def sweep_program(gains: GainSet, *, backend: str,
@@ -660,51 +661,54 @@ def pallas_sweep_demand(
                       occupancy=occupancy, chunk=chunk, devices=devices,
                       cache=cache, node_shards=node_shards,
                       precision=precision, force_interpret=force_interpret)
-        idx_fast = np.flatnonzero(mask)
-        idx_slow = np.flatnonzero(~mask)
-        fast = pallas_sweep_demand(demand, gains.take(idx_fast), **sub_kw)
-        slow = pallas_sweep_demand(demand, gains.take(idx_slow), **sub_kw)
-        merged = []
-        for f in FleetStats._fields:
-            a, b = getattr(fast, f), getattr(slow, f)
-            out = np.empty(len(gains), dtype=a.dtype)
-            out[idx_fast] = a
-            out[idx_slow] = b
-            merged.append(out)
-        return FleetStats(*merged)
+        with span("lab.sweep.stage"):
+            idx_fast = np.flatnonzero(mask)
+            idx_slow = np.flatnonzero(~mask)
+            classes = gains.take(idx_fast), gains.take(idx_slow)
+        fast = pallas_sweep_demand(demand, classes[0], **sub_kw)
+        slow = pallas_sweep_demand(demand, classes[1], **sub_kw)
+        with span("lab.sweep.merge"):
+            return _stitch(len(gains), (idx_fast, fast), (idx_slow, slow))
     n_nodes, n_steps = demand.shape
     _single_device(devices, node_shards, "pallas_sweep_demand")
     backend = _backend(force_interpret)
-    chunk = _resolve_chunk(chunk, len(gains), n_steps, n_nodes, 1)
-    chunk = -(-chunk // TILE_GAINS) * TILE_GAINS
-    n_real = len(gains)
-    gains = _pad_gains(gains, chunk)
-    fn = sweep_program(gains, backend=backend, cache=cache,
-                       interval_s=interval_s, occupancy=occupancy,
-                       precision=precision)
-    dem_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
-    demand_dev = jnp.asarray(
-        np.ascontiguousarray(demand.T, np.float32)).astype(dem_dtype)
-    np_dev = jnp.asarray(_node_pack(node_memory, n_nodes, cache))
-    lp_dev = jnp.asarray(_lane_pack(gains))
-    alive = np.zeros((1, len(gains)), np.float32)
-    alive[0, :n_real] = 1.0
-    alive_dev = jnp.asarray(alive)
-    cols_per_chunk = [(lp_dev[:, lo:lo + chunk],
-                       alive_dev[:, lo:lo + chunk])
-                      for lo in range(0, len(gains), chunk)]
+    with span("lab.sweep.stage"):
+        chunk = _resolve_chunk(chunk, len(gains), n_steps, n_nodes, 1)
+        chunk = -(-chunk // TILE_GAINS) * TILE_GAINS
+        n_real = len(gains)
+        gains = _pad_gains(gains, chunk)
+        fn = sweep_program(gains, backend=backend, cache=cache,
+                           interval_s=interval_s, occupancy=occupancy,
+                           precision=precision)
+        dem_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
+        demand_dev = jnp.asarray(
+            np.ascontiguousarray(demand.T, np.float32)).astype(dem_dtype)
+        np_dev = jnp.asarray(_node_pack(node_memory, n_nodes, cache))
+        lp_dev = jnp.asarray(_lane_pack(gains))
+        alive = np.zeros((1, len(gains)), np.float32)
+        alive[0, :n_real] = 1.0
+        alive_dev = jnp.asarray(alive)
+        cols_per_chunk = [(lp_dev[:, lo:lo + chunk],
+                           alive_dev[:, lo:lo + chunk])
+                          for lo in range(0, len(gains), chunk)]
+    count("lab.sweep.chunks", len(cols_per_chunk))
+    count("lab.sweep.lane_steps.live", n_real * n_steps)
+    count("lab.sweep.lane_steps.run", len(gains) * n_steps)
     if sanitizers_enabled():
         # Compile (and its constant transfers) outside the guard.
         jax.block_until_ready(
             fn(demand_dev, np_dev, *cols_per_chunk[0]))
-    pending = []
-    with dispatch_guard():
-        for cols in cols_per_chunk:
-            pending.append(fn(demand_dev, np_dev, *cols))
-    chunks = [jax.tree_util.tree_map(np.asarray, st) for st in pending]
-    return FleetStats(*(np.concatenate([getattr(c, f)
-                                        for c in chunks])[:n_real]
-                        for f in FleetStats._fields))
+    with span("lab.sweep.dispatch"):
+        pending = []
+        with dispatch_guard():
+            for cols in cols_per_chunk:
+                pending.append(fn(demand_dev, np_dev, *cols))
+    with span("lab.sweep.drain"):
+        chunks = [jax.tree_util.tree_map(np.asarray, st) for st in pending]
+    with span("lab.sweep.merge"):
+        return FleetStats(*(np.concatenate([getattr(c, f)
+                                            for c in chunks])[:n_real]
+                            for f in FleetStats._fields))
 
 
 # ---------------------------------------------------------------------------
@@ -717,8 +721,8 @@ class HalvingSweep(NamedTuple):
     stats: FleetStats          # final-round lanes: (k_last + B,) fields
     scores: np.ndarray         # objective over the same lanes
     survivor_idx: np.ndarray   # (k_last,) original candidate indices
-    rounds: List[dict]         # {horizon, n_candidates, elapsed_s}
-    elapsed_s: float
+    rounds: List[dict]         # {horizon, n_candidates, lanes} per rung
+    elapsed_s: float           # dispatch to drained results
 
 
 def halving_schedule(n_intervals: int, n_candidates: int,
@@ -766,7 +770,7 @@ def _compiled_halving(backend: str, con: _EngineConsts,
                         n_cand, n_base,
                         getattr(objective, "__qualname__", repr(objective)))
 
-    def program(demand_tn, np_rows, lp, alive):
+    def lab_halving(demand_tn, np_rows, lp, alive):
         record_trace("lab.sweep.pallas", chunk=int(lp.shape[1]),
                      horizon=int(demand_tn.shape[0]),
                      nodes=int(demand_tn.shape[1]), mode="halving",
@@ -781,17 +785,19 @@ def _compiled_halving(backend: str, con: _EngineConsts,
         cand = n_cand
         for i, h in enumerate(horizons):
             final = i == len(horizons) - 1
-            if h > t_prev:
-                state, codes = _segment(
-                    state, jax.lax.slice_in_dim(demand_tn, t_prev, h),
-                    lp, np_rows, alive, t0=t_prev, backend=backend,
-                    con=con, names=names, ix=ix)
-                parts.append(codes)
-                t_prev = h
-            prefix = parts[0] if len(parts) == 1 else jnp.concatenate(
-                parts, axis=0)
-            stats = _finalize_lanes(state, prefix, lp, con, names, ix, h)
-            scores = objective(stats)
+            with jax.named_scope(f"rung{i}"):
+                if h > t_prev:
+                    state, codes = _segment(
+                        state, jax.lax.slice_in_dim(demand_tn, t_prev, h),
+                        lp, np_rows, alive, t0=t_prev, backend=backend,
+                        con=con, names=names, ix=ix)
+                    parts.append(codes)
+                    t_prev = h
+                prefix = parts[0] if len(parts) == 1 else jnp.concatenate(
+                    parts, axis=0)
+                stats = _finalize_lanes(state, prefix, lp, con, names, ix,
+                                        h)
+                scores = objective(stats)
             if final:
                 n_out = cand + n_base
                 out_stats = jax.tree_util.tree_map(lambda a: a[:n_out],
@@ -819,7 +825,7 @@ def _compiled_halving(backend: str, con: _EngineConsts,
             cand = k
         raise AssertionError("unreachable")
 
-    return jax.jit(program)
+    return jax.jit(lab_halving)
 
 
 def halving_sweep(
@@ -855,7 +861,11 @@ def halving_sweep(
     program for the in-scan gathers).
 
     Returns a :class:`HalvingSweep`; ``lab.tune.halving_tune`` wraps it
-    into the standard :class:`~repro.lab.tune.TuneResult`.
+    into the standard :class:`~repro.lab.tune.TuneResult`.  The host
+    phases run under the spans ``lab.halving.stage`` / ``dispatch`` /
+    ``drain``, the rungs under ``jax.named_scope("rung<i>")``, and the
+    lane-steps alive and dispatched count into
+    ``lab.halving.lane_steps.{live,run}``.
     """
     demand = np.asarray(demand)
     if cache is not None and float(occupancy) != 1.0:
@@ -874,36 +884,47 @@ def halving_sweep(
     horizons, keeps = halving_schedule(n_steps, len(gains), rounds, keep,
                                        min_survivors)
     n_cand, n_base = len(gains), len(base)
-    lanes = _pad_gains(gains.concat(base), TILE_GAINS)
-    # One law class for the whole lane block: any beyond-paper point
-    # drops every lane to the generic (identical-result) law.
-    plan = plan_specialization(lanes, occupancy)
-    con = _engine_consts(plan, cache, interval_s, occupancy, precision)
-    names = _state_names(con.paper_law, con.has_cache)
-    fn = _compiled_halving(backend, con, names, tuple(horizons),
-                           tuple(keeps), n_cand, n_base, objective)
-    dem_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
-    demand_dev = jnp.asarray(
-        np.ascontiguousarray(demand.T, np.float32)).astype(dem_dtype)
-    np_dev = jnp.asarray(_node_pack(node_memory, n_nodes, cache))
-    lp_dev = jnp.asarray(_lane_pack(lanes))
-    alive = np.zeros((1, len(lanes)), np.float32)
-    alive[0, :n_cand + n_base] = 1.0
-    alive_dev = jnp.asarray(alive)
+    with span("lab.halving.stage"):
+        lanes = _pad_gains(gains.concat(base), TILE_GAINS)
+        # One law class for the whole lane block: any beyond-paper point
+        # drops every lane to the generic (identical-result) law.
+        plan = plan_specialization(lanes, occupancy)
+        con = _engine_consts(plan, cache, interval_s, occupancy, precision)
+        names = _state_names(con.paper_law, con.has_cache)
+        fn = _compiled_halving(backend, con, names, tuple(horizons),
+                               tuple(keeps), n_cand, n_base, objective)
+        dem_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
+        demand_dev = jnp.asarray(
+            np.ascontiguousarray(demand.T, np.float32)).astype(dem_dtype)
+        np_dev = jnp.asarray(_node_pack(node_memory, n_nodes, cache))
+        lp_dev = jnp.asarray(_lane_pack(lanes))
+        alive = np.zeros((1, len(lanes)), np.float32)
+        alive[0, :n_cand + n_base] = 1.0
+        alive_dev = jnp.asarray(alive)
+    # Lanes alive and dispatched per rung (survivors + baseline, padded
+    # to the tile as the program pads them), over each rung's intervals.
+    live = [n_cand + n_base] + [k + n_base for k in keeps]
+    run = [len(lanes)] + [-(-n // TILE_GAINS) * TILE_GAINS
+                          for n in live[1:]]
+    steps = np.diff([0] + horizons)
+    count("lab.halving.lane_steps.live", int(np.dot(live, steps)))
+    count("lab.halving.lane_steps.run", int(np.dot(run, steps)))
     if sanitizers_enabled():
         jax.block_until_ready(fn(demand_dev, np_dev, lp_dev, alive_dev))
     t0 = time.perf_counter()
-    with dispatch_guard():
-        out = fn(demand_dev, np_dev, lp_dev, alive_dev)
-    stats_dev, scores_dev, orig_dev = out
-    stats = jax.tree_util.tree_map(np.asarray, stats_dev)
-    scores = np.asarray(scores_dev)
-    survivor_idx = np.asarray(orig_dev)
+    with span("lab.halving.dispatch"):
+        with dispatch_guard():
+            out = fn(demand_dev, np_dev, lp_dev, alive_dev)
+    with span("lab.halving.drain"):
+        stats_dev, scores_dev, orig_dev = out
+        stats = jax.tree_util.tree_map(np.asarray, stats_dev)
+        scores = np.asarray(scores_dev)
+        survivor_idx = np.asarray(orig_dev)
     elapsed = time.perf_counter() - t0
     counts = [n_cand] + list(keeps)
     round_log = [{"horizon": h,
                   "n_candidates": counts[i] + (n_base if final else 0),
-                  "elapsed_s": elapsed if final else 0.0}
+                  "lanes": run[i]}
                  for i, h in enumerate(horizons)
                  for final in [i == len(horizons) - 1]]
     return HalvingSweep(stats=stats, scores=scores,

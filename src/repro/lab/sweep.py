@@ -66,8 +66,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..analysis.runtime import (dispatch_guard, record_trace,
-                                sanitizers_enabled)
+from ..analysis.runtime import (count, dispatch_guard, record_trace,
+                                sanitizers_enabled, span)
 from ._compat import warn_once
 from ..core.control import ControllerParams, vectorized_step
 from ..core.eviction import policy_model
@@ -575,10 +575,16 @@ def _compiled_sweep(devices: Tuple, paper_law: bool, unit_occupancy: bool,
 
         def fn(demand_tn, m, work_sn, *rest):
             return base(demand_tn, m, *rest, work_sn=work_sn)
+
+    def lab_sweep_chunk(*args):
+        # A named function, so the executable is ``jit_lab_sweep_chunk``
+        # in a profiler trace.
+        return fn(*args)
+
     if len(devices) <= 1:
-        return jax.jit(fn)
+        return jax.jit(lab_sweep_chunk)
     mapped = jax.shard_map(
-        fn, mesh=sweep_mesh(devices, node_shards),
+        lab_sweep_chunk, mesh=sweep_mesh(devices, node_shards),
         in_specs=(_lead_specs(node_shards, app_graph is not None)
                   + (P("gains"),) * 7 + (P(), P())),
         out_specs=P("gains"),
@@ -724,8 +730,12 @@ def sweep_demand(
 
     Every chunk is dispatched before any result is collected, so on an
     asynchronous backend chunk k+1 computes while chunk k's (G,)-scalar
-    stats drain.  ``devices`` shards the gain axis (see module docs);
-    ``node_shards > 1`` additionally splits the node axis, forming a
+    stats drain.  The host phases run under the spans
+    ``lab.sweep.stage`` / ``dispatch`` / ``drain`` / ``merge`` and
+    count ``lab.sweep.chunks`` and ``lab.sweep.lane_steps.{live,run}``
+    (:mod:`repro.analysis.runtime`).  ``devices`` shards the gain axis
+    (see module docs); ``node_shards > 1`` additionally splits the node
+    axis, forming a
     2-D ``(gains x nodes)`` mesh -- ``len(devices)`` must be divisible
     by ``node_shards`` and ``N`` by the shard count.  Chunking and
     sharding are implementation details -- stats are independent of
@@ -761,85 +771,105 @@ def sweep_demand(
         # Mixed law classes: dispatch each class at its own
         # specialization and stitch stats back in gain order, so the
         # beyond-paper points never drag the whole grid off the fast
-        # path.
+        # path.  Each class call opens its own spans, so none nests.
         sub_kw = dict(node_memory=node_memory, interval_s=interval_s,
                       occupancy=occupancy, chunk=chunk, devices=devices,
                       cache=cache, app_graph=app_graph,
                       node_shards=node_shards)
-        idx_fast = np.flatnonzero(mask)
-        idx_slow = np.flatnonzero(~mask)
-        fast = sweep_demand(demand, gains.take(idx_fast), **sub_kw)
-        slow = sweep_demand(demand, gains.take(idx_slow), **sub_kw)
-        merged = []
-        for f in FleetStats._fields:
-            a, b = getattr(fast, f), getattr(slow, f)
-            out = np.empty(len(gains), dtype=a.dtype)
-            out[idx_fast] = a
-            out[idx_slow] = b
-            merged.append(out)
-        return FleetStats(*merged)
-    n_nodes, n_steps = demand.shape
-    demand_tn = np.ascontiguousarray(demand.T, dtype=np.float32)
-    m = np.broadcast_to(np.asarray(node_memory, np.float64),
-                        (n_nodes,)).astype(np.float32)
-    devs = resolve_devices(devices)
-    if len(devs) <= 1:
-        # The bit-exact fallback: one device always runs the plain
-        # jitted program, whatever node_shards was requested.
-        node_shards = 1
-    else:
-        if len(devs) % node_shards:
-            raise ValueError(f"devices ({len(devs)}) must divide evenly "
-                             f"into node_shards={node_shards}")
-        if n_nodes % node_shards:
-            raise ValueError(f"n_nodes ({n_nodes}) must be divisible by "
-                             f"node_shards={node_shards}")
-    gain_shards = len(devs) // node_shards
-    chunk = _resolve_chunk(chunk, len(gains), n_steps, n_nodes, gain_shards)
-    # Pad the ragged tail up to the chunk width (repeating the last gain)
-    # so every call hits the same shape-specialized executable; the
-    # padded rows' stats are sliced off below.
-    n_real = len(gains)
-    if n_real % chunk:
-        pad = GainSet(*(np.repeat(getattr(gains, f.name)[-1:],
-                                  chunk - n_real % chunk)
-                        for f in dataclasses.fields(GainSet)))
-        gains = gains.concat(pad)
-    plan = plan_specialization(gains, occupancy)
-    fn = _compiled_sweep(devs, plan.paper_law, plan.unit_occupancy,
-                         plan.static_bounds, cache, node_shards, app_graph)
-    # Stage every operand device-side (f32) exactly once, before the
-    # guarded dispatch loop, so the loop body is transfer-free (which
-    # dispatch_guard() enforces under PLANECHECK_SANITIZERS=1).
-    stage = _stager(devs, node_shards)
-    lead_p = _lead_specs(node_shards, app_graph is not None)
-    lead = (stage(demand_tn, lead_p[0]), stage(m, lead_p[1]))
-    if app_graph is not None:
-        # The (S+1, N) work matrix compiles against the *global* fleet
-        # (task round-robin and slow-node skew need true node indices)
-        # and is staged once like demand; node sharding splits its
-        # column axis the same way.
-        lead = lead + (stage(compile_graph(app_graph, n_nodes).work_gib,
-                             lead_p[2]),)
-    gain_cols = [np.asarray(getattr(gains, f.name), np.float32)
-                 for f in dataclasses.fields(GainSet)]
-    iv = stage(np.float32(interval_s), P())
-    occ = stage(np.float32(occupancy), P())
-    cols_per_chunk = [[stage(a[lo:lo + chunk], P("gains")) for a in gain_cols]
-                      for lo in range(0, len(gains), chunk)]
+        with span("lab.sweep.stage"):
+            idx_fast = np.flatnonzero(mask)
+            idx_slow = np.flatnonzero(~mask)
+            classes = gains.take(idx_fast), gains.take(idx_slow)
+        fast = sweep_demand(demand, classes[0], **sub_kw)
+        slow = sweep_demand(demand, classes[1], **sub_kw)
+        with span("lab.sweep.merge"):
+            return _stitch(len(gains), (idx_fast, fast), (idx_slow, slow))
+    with span("lab.sweep.stage"):
+        n_nodes, n_steps = demand.shape
+        demand_tn = np.ascontiguousarray(demand.T, dtype=np.float32)
+        m = np.broadcast_to(np.asarray(node_memory, np.float64),
+                            (n_nodes,)).astype(np.float32)
+        devs = resolve_devices(devices)
+        if len(devs) <= 1:
+            # The bit-exact fallback: one device always runs the plain
+            # jitted program, whatever node_shards was requested.
+            node_shards = 1
+        else:
+            if len(devs) % node_shards:
+                raise ValueError(f"devices ({len(devs)}) must divide "
+                                 f"evenly into node_shards={node_shards}")
+            if n_nodes % node_shards:
+                raise ValueError(f"n_nodes ({n_nodes}) must be divisible "
+                                 f"by node_shards={node_shards}")
+        gain_shards = len(devs) // node_shards
+        chunk = _resolve_chunk(chunk, len(gains), n_steps, n_nodes,
+                               gain_shards)
+        # Pad the ragged tail up to the chunk width (repeating the last
+        # gain) so every call hits the same shape-specialized executable;
+        # the padded rows' stats are sliced off below.
+        n_real = len(gains)
+        if n_real % chunk:
+            pad = GainSet(*(np.repeat(getattr(gains, f.name)[-1:],
+                                      chunk - n_real % chunk)
+                            for f in dataclasses.fields(GainSet)))
+            gains = gains.concat(pad)
+        plan = plan_specialization(gains, occupancy)
+        fn = _compiled_sweep(devs, plan.paper_law, plan.unit_occupancy,
+                             plan.static_bounds, cache, node_shards,
+                             app_graph)
+        # Stage every operand device-side (f32) exactly once, before the
+        # guarded dispatch loop, so the loop body is transfer-free
+        # (which dispatch_guard() enforces under PLANECHECK_SANITIZERS=1).
+        stage = _stager(devs, node_shards)
+        lead_p = _lead_specs(node_shards, app_graph is not None)
+        lead = (stage(demand_tn, lead_p[0]), stage(m, lead_p[1]))
+        if app_graph is not None:
+            # The (S+1, N) work matrix compiles against the *global*
+            # fleet (task round-robin and slow-node skew need true node
+            # indices) and is staged once like demand; node sharding
+            # splits its column axis the same way.
+            work = compile_graph(app_graph, n_nodes).work_gib
+            lead = lead + (stage(work, lead_p[2]),)
+        gain_cols = [np.asarray(getattr(gains, f.name), np.float32)
+                     for f in dataclasses.fields(GainSet)]
+        iv = stage(np.float32(interval_s), P())
+        occ = stage(np.float32(occupancy), P())
+        cols_per_chunk = [[stage(a[lo:lo + chunk], P("gains"))
+                           for a in gain_cols]
+                          for lo in range(0, len(gains), chunk)]
+    count("lab.sweep.chunks", len(cols_per_chunk))
+    count("lab.sweep.lane_steps.live", n_real * n_steps)
+    count("lab.sweep.lane_steps.run", len(gains) * n_steps)
     if sanitizers_enabled():
         # Compile (and its constant transfers) happen outside the guard;
         # the guarded loop below then replays only cached executables.
         jax.block_until_ready(
             fn(*lead, *cols_per_chunk[0], iv, occ))
-    pending = []
-    with dispatch_guard():
-        for cols in cols_per_chunk:
-            pending.append(fn(*lead, *cols, iv, occ))
-    chunks = [jax.tree_util.tree_map(np.asarray, st) for st in pending]
-    return FleetStats(*(np.concatenate([getattr(c, f)
-                                        for c in chunks])[:n_real]
-                        for f in FleetStats._fields))
+    with span("lab.sweep.dispatch"):
+        pending = []
+        with dispatch_guard():
+            for cols in cols_per_chunk:
+                pending.append(fn(*lead, *cols, iv, occ))
+    with span("lab.sweep.drain"):
+        chunks = [jax.tree_util.tree_map(np.asarray, st) for st in pending]
+    with span("lab.sweep.merge"):
+        return FleetStats(*(np.concatenate([getattr(c, f)
+                                            for c in chunks])[:n_real]
+                            for f in FleetStats._fields))
+
+
+def _stitch(n: int, *parts) -> FleetStats:
+    """Stats of law-class subsets, back in gain order."""
+    merged = []
+    for f in FleetStats._fields:
+        out = None
+        for idx, stats in parts:
+            a = getattr(stats, f)
+            if out is None:
+                out = np.empty(n, dtype=a.dtype)
+            out[idx] = a
+        merged.append(out)
+    return FleetStats(*merged)
 
 
 def oracle_history(demand: np.ndarray, m, params: ControllerParams,
@@ -894,13 +924,6 @@ class SweepResult:
     @property
     def n_configs(self) -> int:
         return len(self.gains)
-
-    @property
-    def throughput(self) -> float:
-        """node * interval * config closed-loop updates per second."""
-        work = (self.scenario.n_nodes * self.scenario.n_intervals
-                * self.n_configs)
-        return work / self.elapsed_s if self.elapsed_s > 0 else float("inf")
 
     def _score_fn(self, score_fn):
         if score_fn is not None:

@@ -46,6 +46,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from ..analysis.runtime import span
 from ..configs.dynims import PAPER_TABLE_I
 from ..core.control import ControllerParams
 from ._compat import warn_once
@@ -163,7 +164,8 @@ class TuneResult:
     baseline_score: float
     index: int                        # argmax into ``sweep.gains``
     sweep: SweepResult
-    # halving only: per-round records {horizon, n_candidates, elapsed_s}
+    # halving only: per-round records {horizon, n_candidates} plus the
+    # host loop's elapsed_s per round, or the in-scan rung's lanes
     rounds: Optional[List[dict]] = None
     # the objective the search ranked with; summary() reuses it so the
     # leaderboard matches the returned winner under custom objectives.
@@ -182,7 +184,7 @@ class TuneResult:
         s = self.sweep.scores(self.score_fn)
         lines = [f"scenario={self.sweep.scenario.name} "
                  f"configs={self.sweep.n_configs} "
-                 f"throughput={self.sweep.throughput:.2e} node*intv*cfg/s",
+                 f"elapsed={self.sweep.elapsed_s:.3f}s",
                  f"{'rank':>4} {'r0':>6} {'lam':>6} {'lam_g':>6} "
                  f"{'u_max_gib':>9} {'score':>9}"]
         g = self.sweep.gains
@@ -393,38 +395,43 @@ def _halving_tune_pallas(spec: ScenarioSpec, base: ControllerParams,
     :func:`~repro.lab.pallas_sweep.halving_sweep`, and repacks its
     final-round lanes into the standard :class:`TuneResult` --
     ``result.sweep.gains`` holds the surviving candidates with the
-    baseline appended last, same as the host path's final round.
+    baseline appended last, same as the host path's final round.  The
+    scenario build runs under the span ``lab.tune.stage``, the final
+    ranking under ``lab.tune.rank``.
     """
     from .pallas_sweep import halving_sweep
 
-    demand = spec.build_demand(seed=seed)
-    m = spec.build_node_memory(seed=seed)
+    with span("lab.tune.stage"):
+        demand = spec.build_demand(seed=seed)
+        m = spec.build_node_memory(seed=seed)
     hs = halving_sweep(
         demand, gains, GainSet.from_params(base), node_memory=m,
         interval_s=spec.interval_s, occupancy=spec.occupancy,
         cache=spec.cache, rounds=rounds, keep=keep,
         min_survivors=min_survivors, objective=objective, chunk=chunk,
         devices=devices, node_shards=node_shards)
-    survivors = gains.take(hs.survivor_idx).concat(
-        GainSet.from_params(base))
-    sweep = SweepResult(scenario=spec, gains=survivors, stats=hs.stats,
-                        seed=seed, elapsed_s=hs.elapsed_s,
-                        objective=objective)
-    # Final ranking recomputed host-side (float64 numpy over the final
-    # lanes' stats) so it matches the host tuner's arithmetic exactly;
-    # the in-scan rounds selected with the same objective in f32.
-    scores = sweep.scores(objective)
-    best = int(np.argmax(scores))
-    return TuneResult(
-        params=survivors.params_at(best, base),
-        score=float(scores[best]),
-        baseline_params=base,
-        baseline_score=float(scores[-1]),           # base appended last
-        index=best,
-        sweep=sweep,
-        rounds=hs.rounds,
-        score_fn=objective,
-    )
+    with span("lab.tune.rank"):
+        survivors = gains.take(hs.survivor_idx).concat(
+            GainSet.from_params(base))
+        sweep = SweepResult(scenario=spec, gains=survivors, stats=hs.stats,
+                            seed=seed, elapsed_s=hs.elapsed_s,
+                            objective=objective)
+        # Final ranking recomputed host-side (float64 numpy over the
+        # final lanes' stats) so it matches the host tuner's arithmetic
+        # exactly; the in-scan rounds selected with the same objective
+        # in f32.
+        scores = sweep.scores(objective)
+        best = int(np.argmax(scores))
+        return TuneResult(
+            params=survivors.params_at(best, base),
+            score=float(scores[best]),
+            baseline_params=base,
+            baseline_score=float(scores[-1]),       # base appended last
+            index=best,
+            sweep=sweep,
+            rounds=hs.rounds,
+            score_fn=objective,
+        )
 
 
 @dataclasses.dataclass

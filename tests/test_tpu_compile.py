@@ -78,6 +78,31 @@ def test_mosaic_sweep_compiles_at_fleet_size(one_chip, cache):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_halving_program_names_its_kernel_and_rungs(one_chip):
+    """The names a profiler trace shows: the program, the Mosaic kernel
+    and each rung's named scope."""
+    n_cand, nodes, steps = 12, 512, 480
+    gains = _gains(n_cand)
+    base = ps.GainSet.from_params(PAPER_TABLE_I)
+    plan = ps.plan_specialization(gains.concat(base))
+    con = ps._engine_consts(plan, None, 0.1, 1.0, "f32")
+    horizons, keeps = ps.halving_schedule(steps, n_cand, (0.125, 0.5, 1.0),
+                                          0.25, 4)
+    fn = ps._compiled_halving("mosaic", con,
+                              ps._state_names(con.paper_law, con.has_cache),
+                              tuple(horizons), tuple(keeps), n_cand, 1,
+                              ps.default_score)
+    lanes = 2 * ps.TILE_GAINS
+    shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+              for s in ((steps, nodes), (ps._N_NODE_ROWS, nodes),
+                        (ps._N_PARAM_ROWS, lanes), (1, lanes))]
+    text = fn.lower(*shapes).compile().as_text()
+    assert "HloModule jit_lab_halving" in text
+    assert "lab_sweep_kernel" in text
+    for i in range(len(horizons)):
+        assert f"jit(lab_halving)/rung{i}/" in text
+
+
 def _xla_chunk_shapes(chunk, sharding_of):
     lead = [((N_STEPS, N_NODES), "demand"), ((N_NODES,), "m")]
     cols = [((chunk,), "gain")] * 7
@@ -94,6 +119,7 @@ def test_xla_chunk_compiles_at_auto_chunk(topo, one_chip):
     compiled = fn.lower(*_xla_chunk_shapes(chunk, lambda k: one_chip)
                         ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+    assert "HloModule jit_lab_sweep_chunk" in compiled.as_text()
 
 
 NODE_SHARDS = 2
