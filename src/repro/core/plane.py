@@ -56,7 +56,8 @@ import time
 import warnings
 import zlib
 from collections import deque
-from typing import Callable, Dict, Iterable, List, Optional, Tuple, Union
+from typing import (Callable, Dict, Iterable, List, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -749,7 +750,7 @@ class ArrayController:
         self._pending: Dict[str, AggregatedMetrics] = {}  # guarded-by: _lock
         self._fused = make_fused_step(params)     # guarded-by: _lock
         if bus is not None:
-            bus.subscribe(AGG_TOPIC, self.observe)
+            bus.subscribe(AGG_TOPIC, self.observe_many, batch=True)
 
     # -- wiring -------------------------------------------------------------
     def attach_node(self, node: str, registry: StoreRegistry,
@@ -859,8 +860,12 @@ class ArrayController:
 
         Multiple observations of a node within one interval coalesce to
         the latest (the batched law steps once per interval)."""
+        self.observe_many([agg])
+
+    def observe_many(self, aggs: Sequence[AggregatedMetrics]) -> None:
+        """``observe`` each of ``aggs`` in order, under one lock."""
         with self._lock:
-            self._pending[agg.node] = agg
+            self._pending.update((a.node, a) for a in aggs)
 
     def flush(self) -> List[ControlAction]:
         """One control interval: fused decide, then per-node actuation.
@@ -1322,8 +1327,7 @@ class MemoryPlane:
                         samples[name] = s
             count("plane.tick.nodes_sampled", len(monitors))
             with span("plane.tick.publish"):
-                for sample in samples.values():
-                    self.bus.publish(RAW_TOPIC, sample)
+                self.bus.publish_many(RAW_TOPIC, list(samples.values()))
             actions = self.controller.flush()
             count("plane.tick.actions", len(actions))
             with span("plane.tick.record"):

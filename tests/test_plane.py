@@ -6,6 +6,7 @@ import time
 import numpy as np
 import pytest
 
+from repro.analysis.runtime import counts, reset_counters
 from repro.core import (AGG_TOPIC, RAW_TOPIC, ControlPlane, ControllerParams,
                         GiB, MemoryPlane, MemorySample, MessageBus,
                         MetricAggregator, NodeSpec, PlaneSpec, ShardCache,
@@ -39,6 +40,56 @@ def test_bus_isolates_subscriber_exceptions():
     bus.subscribe("t", lambda m: 1 / 0)
     bus.publish("t", "x")              # must not raise
     assert len(bus.errors) == 1
+
+
+def test_bus_publish_many_matches_single_publishes():
+    """Log, offsets, retention, depth and poll cursors end as N single
+    publishes would leave them."""
+    one, many = MessageBus(retention=5), MessageBus(retention=5)
+    assert one.poll("t", group="g") == many.poll("t", group="g") == []
+    for m in range(3):
+        one.publish("t", m)
+    many.publish_many("t", range(3))
+    assert one.poll("t", group="g", max_items=2) == \
+        many.poll("t", group="g", max_items=2) == [0, 1]
+    for m in range(3, 9):
+        one.publish("t", m)
+    many.publish_many("t", list(range(3, 9)))
+    many.publish_many("t", [])
+    assert one.depth("t") == many.depth("t") == 5
+    assert one.poll("t", group="g") == many.poll("t", group="g") == \
+        [4, 5, 6, 7, 8]                         # retention dropped 2, 3
+    assert one.poll("t", group="h") == many.poll("t", group="h")
+    one.publish("t", 9)
+    many.publish_many("t", [9])
+    assert one.poll("t", group="g") == many.poll("t", group="g") == [9]
+
+
+def test_bus_publish_many_delivers_per_message_and_batch():
+    bus = MessageBus()
+    seen, batches = [], []
+    bus.subscribe("t", seen.append)
+    unsub = bus.subscribe("t", batches.append, batch=True)
+    bus.publish_many("t", ["a", "b", "c"])
+    bus.publish("t", "d")
+    assert seen == ["a", "b", "c", "d"]
+    assert batches == [["a", "b", "c"], ["d"]]
+    unsub()
+    bus.publish_many("t", ["e"])
+    assert seen[-1] == "e" and len(batches) == 2
+
+
+def test_bus_publish_many_isolates_subscriber_exceptions():
+    bus = MessageBus()
+    seen = []
+    bus.subscribe("t", lambda m: 1 / m)
+    bus.subscribe("t", lambda ms: [][len(ms)], batch=True)
+    bus.subscribe("t", seen.append)
+    bus.publish_many("t", [1, 0, 2, 0])             # must not raise
+    assert seen == [1, 0, 2, 0]
+    kinds = sorted(type(e).__name__ for _, e in bus.errors)
+    assert kinds == ["IndexError", "ZeroDivisionError", "ZeroDivisionError"]
+    assert all(t == "t" for t, _ in bus.errors)
 
 
 def test_sample_json_roundtrip():
@@ -194,6 +245,29 @@ def test_array_scalar_parity_256_heterogeneous_nodes(variant):
     ref = np.array([planes["scalar"].capacity(f"n{i}") for i in range(n)])
     got = np.array([planes["array"].capacity(f"n{i}") for i in range(n)])
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e4)
+
+
+@pytest.mark.parametrize("backend", ["array", "scalar"])
+def test_tick_aggregates_the_fleet_in_one_pass(backend):
+    """One tick of an N-node plane is one aggregator pass of N rows, and
+    the controller still sees every node's aggregate, in node order."""
+    rng = np.random.default_rng(7)
+    n, t = 48, 4
+    M = np.full(n, 125 * GiB)
+    base = ControllerParams(total_memory=125 * GiB)
+    demand = rng.uniform(0.3, 0.9, (n, t)) * M[:, None]
+    plane = _heterogeneous_fleet(backend, base, M, np.zeros(n),
+                                 np.full(n, 60 * GiB), np.full(n, 30 * GiB),
+                                 demand)
+    plane.tick()
+    reset_counters()
+    actions = plane.tick()
+    assert counts("stream.agg.") == {"stream.agg.batches": 1,
+                                     "stream.agg.rows": n}
+    assert [a.node for a in actions] == [f"n{i}" for i in range(n)]
+    aggs = plane.bus.poll(AGG_TOPIC, group="audit", max_items=4 * n)
+    assert [a.node for a in aggs] == [f"n{i}" for i in range(n)] * 2
+    assert all(a.n_samples == 2 for a in aggs[n:])
 
 
 def test_memory_plane_lifecycle_restart():
