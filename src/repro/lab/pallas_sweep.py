@@ -81,8 +81,9 @@ from .scenarios import CacheSpec
 from .score import (FleetStats, OVER_R0_EPS, SETTLE_TOL, default_score,
                     finalize_fleet_stats, hpl_slowdown_curve, kahan_add,
                     quantile_from_codes, utilization_codes)
-from .sweep import (GainSet, _resolve_chunk, _stitch, paper_law_mask,
-                    plan_specialization, resolve_devices)
+from .sweep import (GainSet, _Chunks, _law_classes, _pad_gains,
+                    _resolve_chunk, _run_chunks, _run_law_classes,
+                    plan_specialization, resolve_devices, stage_demand)
 
 # Gain lanes per kernel tile (the sublane axis of the VPU's 8x128
 # geometry) and intervals walked per sequential grid step.  A segment
@@ -507,15 +508,6 @@ def _reciprocals(lp, np_rows):
             .at[_INV_W].set(1.0 / np_rows[_W]))
 
 
-def _pad_gains(gains: GainSet, multiple: int) -> GainSet:
-    short = (-len(gains)) % multiple
-    if not short:
-        return gains
-    pad = GainSet(*(np.repeat(getattr(gains, f.name)[-1:], short)
-                    for f in dataclasses.fields(GainSet)))
-    return gains.concat(pad)
-
-
 def _backend(force_interpret: Optional[bool]) -> str:
     if force_interpret is None:
         force_interpret = os.environ.get("PALLAS_SWEEP_INTERPRET",
@@ -655,60 +647,41 @@ def pallas_sweep_demand(
         if not 1 <= horizon <= demand.shape[1]:
             raise ValueError(f"horizon must be in [1, {demand.shape[1]}]")
         demand = demand[:, :horizon]
-    mask = paper_law_mask(gains)
-    if mask.any() and not mask.all():
-        sub_kw = dict(node_memory=node_memory, interval_s=interval_s,
-                      occupancy=occupancy, chunk=chunk, devices=devices,
-                      cache=cache, node_shards=node_shards,
-                      precision=precision, force_interpret=force_interpret)
-        with span("lab.sweep.stage"):
-            idx_fast = np.flatnonzero(mask)
-            idx_slow = np.flatnonzero(~mask)
-            classes = gains.take(idx_fast), gains.take(idx_slow)
-        fast = pallas_sweep_demand(demand, classes[0], **sub_kw)
-        slow = pallas_sweep_demand(demand, classes[1], **sub_kw)
-        with span("lab.sweep.merge"):
-            return _stitch(len(gains), (idx_fast, fast), (idx_slow, slow))
     n_nodes, n_steps = demand.shape
     _single_device(devices, node_shards, "pallas_sweep_demand")
     backend = _backend(force_interpret)
     with span("lab.sweep.stage"):
-        chunk = _resolve_chunk(chunk, len(gains), n_steps, n_nodes, 1)
-        chunk = -(-chunk // TILE_GAINS) * TILE_GAINS
-        n_real = len(gains)
-        gains = _pad_gains(gains, chunk)
-        fn = sweep_program(gains, backend=backend, cache=cache,
-                           interval_s=interval_s, occupancy=occupancy,
-                           precision=precision)
-        dem_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
-        demand_dev = jnp.asarray(
-            np.ascontiguousarray(demand.T, np.float32)).astype(dem_dtype)
+        # Demand and node rows go up once; law classes share them.
+        demand_dev = stage_demand(demand, _demand_dtype(precision))
         np_dev = jnp.asarray(_node_pack(node_memory, n_nodes, cache))
-        lp_dev = jnp.asarray(_lane_pack(gains))
-        alive = np.zeros((1, len(gains)), np.float32)
-        alive[0, :n_real] = 1.0
-        alive_dev = jnp.asarray(alive)
-        cols_per_chunk = [(lp_dev[:, lo:lo + chunk],
-                           alive_dev[:, lo:lo + chunk])
-                          for lo in range(0, len(gains), chunk)]
-    count("lab.sweep.chunks", len(cols_per_chunk))
-    count("lab.sweep.lane_steps.live", n_real * n_steps)
-    count("lab.sweep.lane_steps.run", len(gains) * n_steps)
-    if sanitizers_enabled():
-        # Compile (and its constant transfers) outside the guard.
-        jax.block_until_ready(
-            fn(demand_dev, np_dev, *cols_per_chunk[0]))
-    with span("lab.sweep.dispatch"):
-        pending = []
-        with dispatch_guard():
-            for cols in cols_per_chunk:
-                pending.append(fn(demand_dev, np_dev, *cols))
-    with span("lab.sweep.drain"):
-        chunks = [jax.tree_util.tree_map(np.asarray, st) for st in pending]
-    with span("lab.sweep.merge"):
-        return FleetStats(*(np.concatenate([getattr(c, f)
-                                            for c in chunks])[:n_real]
-                            for f in FleetStats._fields))
+
+        def pack(gains: GainSet) -> _Chunks:
+            width = _resolve_chunk(chunk, len(gains), n_steps, n_nodes, 1)
+            width = -(-width // TILE_GAINS) * TILE_GAINS
+            n_real = len(gains)
+            gains = _pad_gains(gains, width)
+            fn = sweep_program(gains, backend=backend, cache=cache,
+                               interval_s=interval_s, occupancy=occupancy,
+                               precision=precision)
+            lp_dev = jnp.asarray(_lane_pack(gains))
+            alive = np.zeros((1, len(gains)), np.float32)
+            alive[0, :n_real] = 1.0
+            alive_dev = jnp.asarray(alive)
+            calls = [(demand_dev, np_dev, lp_dev[:, lo:lo + width],
+                      alive_dev[:, lo:lo + width])
+                     for lo in range(0, len(gains), width)]
+            return _Chunks(fn, calls, n_real, len(gains), n_steps)
+
+        classes = _law_classes(gains)
+        job = pack(gains) if classes is None else None
+    if classes is None:
+        return _run_chunks(job)
+    return _run_law_classes(gains, classes, pack)
+
+
+def _demand_dtype(precision: str):
+    """The demand stream's device dtype; all state stays f32."""
+    return jnp.bfloat16 if precision == "bf16" else jnp.float32
 
 
 # ---------------------------------------------------------------------------
@@ -893,9 +866,7 @@ def halving_sweep(
         names = _state_names(con.paper_law, con.has_cache)
         fn = _compiled_halving(backend, con, names, tuple(horizons),
                                tuple(keeps), n_cand, n_base, objective)
-        dem_dtype = jnp.bfloat16 if precision == "bf16" else jnp.float32
-        demand_dev = jnp.asarray(
-            np.ascontiguousarray(demand.T, np.float32)).astype(dem_dtype)
+        demand_dev = stage_demand(demand, _demand_dtype(precision))
         np_dev = jnp.asarray(_node_pack(node_memory, n_nodes, cache))
         lp_dev = jnp.asarray(_lane_pack(lanes))
         alive = np.zeros((1, len(lanes)), np.float32)

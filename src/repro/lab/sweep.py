@@ -59,7 +59,8 @@ import dataclasses
 import functools
 import hashlib
 import time
-from typing import NamedTuple, Optional, Sequence, Tuple, Union
+from typing import (Callable, List, NamedTuple, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -621,6 +622,47 @@ def _stager(devices: Tuple, node_shards: int):
     return lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
 
 
+@functools.lru_cache(maxsize=None)
+def _transposer(dtype: np.dtype, sharding: Optional[NamedSharding]):
+    """The jitted ``(N, T) f32 -> (T, N) dtype`` program, per placement."""
+    placement = "default" if sharding is None else repr(sharding)
+
+    def lab_stage_demand(demand_nt):
+        # A named function, so the executable is ``jit_lab_stage_demand``
+        # in a profiler trace.
+        record_trace("lab.sweep.stage_demand", nodes=demand_nt.shape[0],
+                     steps=demand_nt.shape[1], dtype=dtype.name,
+                     placement=placement)
+        return demand_nt.T.astype(dtype)
+
+    return jax.jit(lab_stage_demand, out_shardings=sharding)
+
+
+def stage_demand(demand: np.ndarray, dtype=np.float32,
+                 sharding: Optional[NamedSharding] = None) -> jax.Array:
+    """Put an ``(N, T)`` host demand on the device as the ``(T, N)``
+    operand the sweep programs read, once per entry-point call.
+
+    The host only casts, contiguously (no copy for C-ordered float32);
+    the device transposes and, for ``dtype`` bfloat16, rounds.  Either
+    step is exact or elementwise, so the operand equals
+    ``np.ascontiguousarray(demand.T, np.float32)`` cast to ``dtype``
+    bit for bit.  ``sharding`` places the ``(T, N)`` operand on a mesh:
+    the block goes up under the transposed spec, so each device
+    transposes its own rows; ``None`` takes the default device.  Counts
+    ``lab.demand.staged`` and ``lab.demand.staged_bytes``.
+    """
+    host = np.ascontiguousarray(demand, np.float32)
+    count("lab.demand.staged")
+    count("lab.demand.staged_bytes", host.nbytes)
+    if sharding is None:
+        block = jax.device_put(host)
+    else:
+        block = jax.device_put(host, NamedSharding(
+            sharding.mesh, P(*reversed(sharding.spec))))
+    return _transposer(np.dtype(dtype), sharding)(block)
+
+
 def resolve_devices(devices: Union[None, int, Sequence] = None) -> Tuple:
     """Normalize the ``devices`` knob to a tuple of jax devices.
 
@@ -733,7 +775,8 @@ def sweep_demand(
     stats drain.  The host phases run under the spans
     ``lab.sweep.stage`` / ``dispatch`` / ``drain`` / ``merge`` and
     count ``lab.sweep.chunks`` and ``lab.sweep.lane_steps.{live,run}``
-    (:mod:`repro.analysis.runtime`).  ``devices`` shards the gain axis
+    (:mod:`repro.analysis.runtime`); the demand goes up once a call
+    (:func:`stage_demand`).  ``devices`` shards the gain axis
     (see module docs); ``node_shards > 1`` additionally splits the node
     axis, forming a
     2-D ``(gains x nodes)`` mesh -- ``len(devices)`` must be divisible
@@ -744,7 +787,8 @@ def sweep_demand(
     regardless of ``node_shards``).  ``cache`` enables CacheLoop (see
     :class:`~repro.lab.scenarios.CacheSpec`); a gain set mixing
     paper-faithful and beyond-paper points is partitioned by law class
-    so each class runs its own specialized executable.  ``app_graph``
+    so each class runs its own specialized executable on the same
+    staged operands.  ``app_graph``
     attaches a stage-DAG co-simulation
     (:class:`~repro.lab.appgraph.AppGraphSpec`) scored through
     ``FleetStats.makespan``; ``None`` compiles the exact pre-AppGraph
@@ -766,29 +810,8 @@ def sweep_demand(
         if not 1 <= horizon <= demand.shape[1]:
             raise ValueError(f"horizon must be in [1, {demand.shape[1]}]")
         demand = demand[:, :horizon]
-    mask = paper_law_mask(gains)
-    if mask.any() and not mask.all():
-        # Mixed law classes: dispatch each class at its own
-        # specialization and stitch stats back in gain order, so the
-        # beyond-paper points never drag the whole grid off the fast
-        # path.  Each class call opens its own spans, so none nests.
-        sub_kw = dict(node_memory=node_memory, interval_s=interval_s,
-                      occupancy=occupancy, chunk=chunk, devices=devices,
-                      cache=cache, app_graph=app_graph,
-                      node_shards=node_shards)
-        with span("lab.sweep.stage"):
-            idx_fast = np.flatnonzero(mask)
-            idx_slow = np.flatnonzero(~mask)
-            classes = gains.take(idx_fast), gains.take(idx_slow)
-        fast = sweep_demand(demand, classes[0], **sub_kw)
-        slow = sweep_demand(demand, classes[1], **sub_kw)
-        with span("lab.sweep.merge"):
-            return _stitch(len(gains), (idx_fast, fast), (idx_slow, slow))
+    n_nodes, n_steps = demand.shape
     with span("lab.sweep.stage"):
-        n_nodes, n_steps = demand.shape
-        demand_tn = np.ascontiguousarray(demand.T, dtype=np.float32)
-        m = np.broadcast_to(np.asarray(node_memory, np.float64),
-                            (n_nodes,)).astype(np.float32)
         devs = resolve_devices(devices)
         if len(devs) <= 1:
             # The bit-exact fallback: one device always runs the plain
@@ -801,28 +824,18 @@ def sweep_demand(
             if n_nodes % node_shards:
                 raise ValueError(f"n_nodes ({n_nodes}) must be divisible "
                                  f"by node_shards={node_shards}")
-        gain_shards = len(devs) // node_shards
-        chunk = _resolve_chunk(chunk, len(gains), n_steps, n_nodes,
-                               gain_shards)
-        # Pad the ragged tail up to the chunk width (repeating the last
-        # gain) so every call hits the same shape-specialized executable;
-        # the padded rows' stats are sliced off below.
-        n_real = len(gains)
-        if n_real % chunk:
-            pad = GainSet(*(np.repeat(getattr(gains, f.name)[-1:],
-                                      chunk - n_real % chunk)
-                            for f in dataclasses.fields(GainSet)))
-            gains = gains.concat(pad)
-        plan = plan_specialization(gains, occupancy)
-        fn = _compiled_sweep(devs, plan.paper_law, plan.unit_occupancy,
-                             plan.static_bounds, cache, node_shards,
-                             app_graph)
         # Stage every operand device-side (f32) exactly once, before the
-        # guarded dispatch loop, so the loop body is transfer-free
+        # guarded dispatch loops, so the loop bodies are transfer-free
         # (which dispatch_guard() enforces under PLANECHECK_SANITIZERS=1).
+        # Law classes share all but their gain columns.
         stage = _stager(devs, node_shards)
         lead_p = _lead_specs(node_shards, app_graph is not None)
-        lead = (stage(demand_tn, lead_p[0]), stage(m, lead_p[1]))
+        placement = None if len(devs) <= 1 else NamedSharding(
+            sweep_mesh(devs, node_shards), lead_p[0])
+        m = np.broadcast_to(np.asarray(node_memory, np.float64),
+                            (n_nodes,)).astype(np.float32)
+        lead = (stage_demand(demand, sharding=placement),
+                stage(m, lead_p[1]))
         if app_graph is not None:
             # The (S+1, N) work matrix compiles against the *global*
             # fleet (task round-robin and slow-node skew need true node
@@ -830,31 +843,99 @@ def sweep_demand(
             # splits its column axis the same way.
             work = compile_graph(app_graph, n_nodes).work_gib
             lead = lead + (stage(work, lead_p[2]),)
-        gain_cols = [np.asarray(getattr(gains, f.name), np.float32)
-                     for f in dataclasses.fields(GainSet)]
-        iv = stage(np.float32(interval_s), P())
-        occ = stage(np.float32(occupancy), P())
-        cols_per_chunk = [[stage(a[lo:lo + chunk], P("gains"))
-                           for a in gain_cols]
-                          for lo in range(0, len(gains), chunk)]
-    count("lab.sweep.chunks", len(cols_per_chunk))
-    count("lab.sweep.lane_steps.live", n_real * n_steps)
-    count("lab.sweep.lane_steps.run", len(gains) * n_steps)
+        tail = (stage(np.float32(interval_s), P()),
+                stage(np.float32(occupancy), P()))
+
+        def pack(gains: GainSet) -> _Chunks:
+            width = _resolve_chunk(chunk, len(gains), n_steps, n_nodes,
+                                   len(devs) // node_shards)
+            # Pad the ragged tail up to the chunk width so every call
+            # hits the same shape-specialized executable; the padded
+            # rows' stats are sliced off after the drain.
+            n_real = len(gains)
+            gains = _pad_gains(gains, width)
+            plan = plan_specialization(gains, occupancy)
+            fn = _compiled_sweep(devs, plan.paper_law, plan.unit_occupancy,
+                                 plan.static_bounds, cache, node_shards,
+                                 app_graph)
+            gain_cols = [np.asarray(getattr(gains, f.name), np.float32)
+                         for f in dataclasses.fields(GainSet)]
+            calls = [(*lead, *(stage(a[lo:lo + width], P("gains"))
+                               for a in gain_cols), *tail)
+                     for lo in range(0, len(gains), width)]
+            return _Chunks(fn, calls, n_real, len(gains), n_steps)
+
+        classes = _law_classes(gains)
+        job = pack(gains) if classes is None else None
+    if classes is None:
+        return _run_chunks(job)
+    return _run_law_classes(gains, classes, pack)
+
+
+class _Chunks(NamedTuple):
+    """One law class, packed: its program and each chunk's operands."""
+
+    fn: Callable
+    calls: List[tuple]
+    n_real: int          # gain points, before padding
+    n_run: int           # lanes dispatched, padding included
+    n_steps: int
+
+
+def _pad_gains(gains: GainSet, multiple: int) -> GainSet:
+    """Repeat the last gain point up to a multiple of ``multiple``."""
+    short = (-len(gains)) % multiple
+    if not short:
+        return gains
+    pad = GainSet(*(np.repeat(getattr(gains, f.name)[-1:], short)
+                    for f in dataclasses.fields(GainSet)))
+    return gains.concat(pad)
+
+
+def _law_classes(gains: GainSet) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+    """Indices of the paper-law and the beyond-paper points, or None
+    where every point shares one law class."""
+    mask = paper_law_mask(gains)
+    if mask.all() or not mask.any():
+        return None
+    return np.flatnonzero(mask), np.flatnonzero(~mask)
+
+
+def _run_law_classes(gains: GainSet, classes, pack) -> FleetStats:
+    """Run each law class at its own specialization on the operands
+    staged once, and stitch stats back in gain order, so the
+    beyond-paper points never drag the whole grid off the fast path.
+    Each class packs its gains under its own ``lab.sweep.stage`` span,
+    after the entry point's, so no stage span nests."""
+    parts = []
+    for idx in classes:
+        with span("lab.sweep.stage"):
+            job = pack(gains.take(idx))
+        parts.append((idx, _run_chunks(job)))
+    with span("lab.sweep.merge"):
+        return _stitch(len(gains), *parts)
+
+
+def _run_chunks(job: _Chunks) -> FleetStats:
+    """Dispatch every chunk, then drain them all: chunk k+1 computes
+    while chunk k's (G,)-scalar stats come back."""
+    count("lab.sweep.chunks", len(job.calls))
+    count("lab.sweep.lane_steps.live", job.n_real * job.n_steps)
+    count("lab.sweep.lane_steps.run", job.n_run * job.n_steps)
     if sanitizers_enabled():
         # Compile (and its constant transfers) happen outside the guard;
         # the guarded loop below then replays only cached executables.
-        jax.block_until_ready(
-            fn(*lead, *cols_per_chunk[0], iv, occ))
+        jax.block_until_ready(job.fn(*job.calls[0]))
     with span("lab.sweep.dispatch"):
         pending = []
         with dispatch_guard():
-            for cols in cols_per_chunk:
-                pending.append(fn(*lead, *cols, iv, occ))
+            for args in job.calls:
+                pending.append(job.fn(*args))
     with span("lab.sweep.drain"):
         chunks = [jax.tree_util.tree_map(np.asarray, st) for st in pending]
     with span("lab.sweep.merge"):
         return FleetStats(*(np.concatenate([getattr(c, f)
-                                            for c in chunks])[:n_real]
+                                            for c in chunks])[:job.n_real]
                             for f in FleetStats._fields))
 
 

@@ -570,6 +570,29 @@ def test_sweep_compiles_once_per_shape(planecheck_sanitizers):
     assert excess_traces("lab.sweep.chunk") == {}
 
 
+@pytest.mark.parametrize("engine,steps", [("xla", 41), ("pallas", 43)])
+def test_demand_staging_compiles_once_per_shape(planecheck_sanitizers,
+                                                engine, steps):
+    pytest.importorskip("jax")
+    from repro.core.cluster_sim import paper_controller_params
+    from repro.core.traces import fleet_demand_traces
+    from repro.lab import GainSet, sweep_demand
+
+    p = paper_controller_params()
+    # shapes unique to this test so parallel-file runs cannot collide
+    demand = fleet_demand_traces(3, steps, p.interval_s, seed=11)
+    gains = GainSet(r0=0.95, lam=(0.4, 0.8, 1.2), lam_grant=(0.4, 0.25, 1.2),
+                    u_min=0.0, u_max=p.u_max)          # two law classes
+    reset_trace_counts()
+    for _ in range(3):
+        sweep_demand(demand, gains, node_memory=p.total_memory,
+                     interval_s=p.interval_s, engine=engine)
+    staged = trace_counts("lab.sweep.stage_demand")
+    assert list(staged.values()) == [1]
+    assert f"steps={steps}" in next(iter(staged))
+    assert excess_traces("lab.sweep.") == {}
+
+
 def test_fused_step_compiles_once_per_fleet_shape(planecheck_sanitizers):
     jnp = pytest.importorskip("jax.numpy")
     from repro.core.control import ControllerParams

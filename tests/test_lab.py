@@ -1,5 +1,6 @@
 """ScenarioLab tests: registry, sweep-engine parity, scoring, tuning."""
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -11,6 +12,7 @@ from repro.lab import (FleetStats, GainSet, ScenarioSpec, compute_fleet_stats,
                        default_score, get_scenario, grid_gains,
                        list_scenarios, random_gains, register_scenario,
                        run_sweep, stats_to_dict, sweep_demand, tune_gains)
+from repro.lab.sweep import paper_law_mask, stage_demand
 
 
 # ---------------------------------------------------------------------------
@@ -131,6 +133,49 @@ def test_sweep_chunking_invariant():
     for f in FleetStats._fields:
         np.testing.assert_allclose(getattr(a.stats, f), getattr(b.stats, f),
                                    rtol=1e-6, err_msg=f)
+
+
+# ---------------------------------------------------------------------------
+# Demand staging
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", ["float64", "float32", "horizon_view"])
+def test_staged_demand_equals_host_transpose(layout, dtype):
+    """Cast on the host, transpose on the device: the operand is the
+    host transpose bit for bit (then rounded on the device for bf16)."""
+    demand = np.random.default_rng(4).uniform(20, 90, (12, 70)) * GiB
+    if layout == "float32":
+        demand = demand.astype(np.float32)
+    elif layout == "horizon_view":
+        demand = demand[:, :45]           # a strided view, as horizon= cuts
+    host = np.ascontiguousarray(demand.T, np.float32)
+    want = np.asarray(jnp.asarray(host).astype(dtype))
+    got = np.asarray(stage_demand(demand, jnp.dtype(dtype)))
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("engine", ["xla", "pallas"])
+def test_mixed_law_sweep_equals_stitched_class_calls(engine):
+    """Both law classes run on the demand staged once; each class's
+    stats equal its own single-class call's, bit for bit."""
+    p = paper_controller_params()
+    demand = fleet_demand_traces(24, 150, p.interval_s, seed=9)
+    lam = np.linspace(0.3, 1.5, 7)
+    grant = lam.copy()
+    grant[[1, 4, 5]] = 0.25
+    gains = GainSet(r0=0.95, lam=lam, lam_grant=grant, u_min=0.0,
+                    u_max=p.u_max)
+    kw = dict(node_memory=p.total_memory, interval_s=p.interval_s,
+              engine=engine)
+    mixed = sweep_demand(demand, gains, **kw)
+    mask = paper_law_mask(gains)
+    for idx in (np.flatnonzero(mask), np.flatnonzero(~mask)):
+        part = sweep_demand(demand, gains.take(idx), **kw)
+        for f in FleetStats._fields:
+            np.testing.assert_array_equal(getattr(mixed, f)[idx],
+                                          getattr(part, f), err_msg=f)
 
 
 def test_gain_set_construction_and_roundtrip():

@@ -6,6 +6,7 @@ in a subprocess with 8 forced host devices, one real lower+compile of
 each cell kind on a small mesh to keep the machinery honest in CI.
 """
 
+import functools
 import json
 import os
 import subprocess
@@ -86,6 +87,67 @@ def test_small_mesh_dryrun_all_kinds():
     out = json.loads(proc.stdout.strip().splitlines()[-1])
     assert set(out) == {"train_4k", "prefill_32k", "decode_32k"}
     assert all(v["flops"] > 0 for v in out.values())
+
+
+STAGING_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+import json
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding
+from repro.analysis import runtime
+from repro.lab import GainSet, sweep_demand
+from repro.lab.sweep import _lead_specs, resolve_devices, stage_demand, sweep_mesh
+
+target = NamedSharding(sweep_mesh(resolve_devices(8), 2),
+                       _lead_specs(2, False)[0])
+demand = np.random.default_rng(0).uniform(20, 90, (16, 50)) * 2**30
+host = np.ascontiguousarray(demand.T, np.float32)
+out = {}
+for dtype in ("float32", "bfloat16"):
+    got = stage_demand(demand, jnp.dtype(dtype), sharding=target)
+    want = np.asarray(jnp.asarray(host).astype(dtype))
+    out[dtype] = {
+        "bits": (np.asarray(got).dtype == want.dtype
+                 and np.asarray(got).tobytes() == want.tobytes()),
+        "placed": got.sharding.is_equivalent_to(target, 2),
+        "shards": sorted({tuple(s.data.shape) for s in got.addressable_shards})}
+runtime.reset_counters()
+lam = np.linspace(0.3, 1.5, 8)
+gains = GainSet(r0=0.95, lam=lam, lam_grant=np.where(lam > 1, 0.25, lam),
+                u_min=0.0, u_max=60 * 2**30)        # two law classes
+sweep_demand(demand, gains, node_memory=125 * 2**30, devices=8,
+             node_shards=2)
+out["staged"] = runtime.counts("lab.demand.")
+print(json.dumps(out))
+"""
+
+
+@functools.lru_cache(maxsize=None)
+def _mesh_staging():
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    proc = subprocess.run([sys.executable, "-c", STAGING_SCRIPT],
+                          capture_output=True, text=True, env=env,
+                          timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_mesh_staged_demand_equals_host_transpose(dtype):
+    """On the 8-device (gains x nodes) mesh with node_shards=2 the
+    staged (T, N) operand is the host transpose bit for bit, split by
+    node columns as the chunk program reads it."""
+    got = _mesh_staging()[dtype]
+    assert got["bits"] and got["placed"]
+    assert got["shards"] == [[50, 8]]
+
+
+def test_mesh_mixed_sweep_stages_demand_once():
+    assert _mesh_staging()["staged"] == {
+        "lab.demand.staged": 1, "lab.demand.staged_bytes": 16 * 50 * 4}
 
 
 def test_dryrun_artifacts_if_present():

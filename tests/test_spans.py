@@ -114,6 +114,22 @@ def test_mixed_law_classes_pad_per_class_and_never_nest_stages():
     assert runtime.span_stats()["lab.sweep.merge"].count == 3
 
 
+@pytest.mark.parametrize("entry", ["xla", "pallas", "halving"])
+def test_demand_staged_once_per_call(entry):
+    # Mixed law classes on the sweeps; the halving runs them as one.
+    demand = _demand(n=8, t=64)
+    for _ in range(2):
+        if entry == "halving":
+            halving_sweep(demand, _gains(12, asym=3), _gains(1),
+                          node_memory=125 * 2**30)
+        else:
+            sweep_demand(demand, _gains(7, asym=3), node_memory=125 * 2**30,
+                         devices=1, engine=entry)
+    assert runtime.counts("lab.demand.") == {
+        "lab.demand.staged": 2,
+        "lab.demand.staged_bytes": 2 * demand.size * 4}
+
+
 def test_halving_counts_live_and_padded_lanes_per_rung():
     demand = _demand(n=8, t=64)
     hs = halving_sweep(demand, _gains(12), _gains(1), node_memory=125 * 2**30,
