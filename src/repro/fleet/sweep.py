@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, NamedTuple, Optional, Sequence, Tuple, Union
+from typing import NamedTuple, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -41,14 +41,12 @@ from ..analysis.runtime import (dispatch_guard, record_trace,
                                 sanitizers_enabled)
 from ..core.control import vectorized_step
 from ..core.traces import GiB
-from ..lab.score import (FleetStats, OVER_R0_EPS, SETTLE_TOL,
-                         compute_fleet_stats, finalize_fleet_stats,
-                         kahan_add, quantile_from_codes, utilization_codes)
+from ..lab import step
+from ..lab.score import FleetStats, compute_fleet_stats, utilization_codes
 from ..lab._compat import warn_once
-from ..lab.sweep import (GainSet, _resolve_engine, _stager, resolve_devices,
-                         sweep_mesh)
+from ..lab.sweep import (GainSet, _check_args, _mesh_devices, _pad_gains,
+                         _resolve_engine, _stager, sweep_mesh)
 from .arbiter import MIN_TENANT_BUDGET, arbitrate, arbitrate_reference
-from .specs import FleetSpec
 
 Array = Union[np.ndarray, "jnp.ndarray"]
 
@@ -116,19 +114,18 @@ def _one_fleet_gain(demand, m, inv_m, w, fl, r0_g, lam_g, lam_grant_g,
     n_epochs, ep_len, k, n_nodes = demand.shape
     f_eff = _effective_floors(fl, m, jnp)
     b0 = _initial_budgets(w, fl, m, jnp)
-    inv_r0_g = 1.0 / r0_g
-    thr_over = r0_g + OVER_R0_EPS
-    thr_settle = r0_g + SETTLE_TOL
+    g = step.gain_rows(r0_g, lam_g, lam_grant_g, u_min_g, u_max_g, db_g,
+                       ff_g)
     inv_gib = jnp.float32(1.0 / GiB)
     inv_ep = jnp.float32(1.0 / ep_len)
     zeros = jnp.zeros((n_nodes,), jnp.float32)
-    cnt_dtype = jnp.int16 if n_epochs * ep_len < 2**15 else jnp.int32
-    izeros = jnp.zeros((n_nodes,), cnt_dtype)
+    izeros = jnp.zeros((n_nodes,), step.count_dtype(n_epochs * ep_len))
     u0 = jnp.minimum(u_max_g, b0)
+    acc_names = step.ACC + ("t",)
 
     def interval_step(carry, d):
         u, b, v_prev, usage, acc = carry
-        (us, us_c, cs, cs_c, c2, mx, n_r0, n_viol, last_bad, t) = acc
+        s = dict(zip(acc_names, acc))
         v = d + u                                      # saturated store
         # Feedforward applied to v up front (identical to the law's own
         # branch, which trace-time-resolves from a Python float a
@@ -138,23 +135,17 @@ def _one_fleet_gain(demand, m, inv_m, w, fl, r0_g, lam_g, lam_grant_g,
         u_next = vectorized_step(
             u, v_eff, total_memory=b, r0=r0_g, lam=lam_g,
             u_min=jnp.minimum(u_min_g, u_max_eff), u_max=u_max_eff,
-            lam_grant=lam_grant_g, deadband=db_g, inv_r0=inv_r0_g)
+            lam_grant=lam_grant_g, deadband=db_g, inv_r0=g.inv_r0)
         r = v.sum(0) * inv_m                           # fleet-level (N,)
-        us, us_c = kahan_add(us, us_c, r)
-        cap_gib = u_next.sum(0) * inv_gib
-        cs, cs_c = kahan_add(cs, cs_c, cap_gib)
-        c2 = c2 + cap_gib * cap_gib
-        mx = jnp.maximum(mx, r)
-        n_r0 = n_r0 + (r > thr_over)
-        n_viol = n_viol + (r > 1.0)
-        last_bad = jnp.where(r > thr_settle, t, last_bad)
-        acc = (us, us_c, cs, cs_c, c2, mx, n_r0, n_viol, last_bad, t + 1)
+        out = step.acc_update(s, r, u_next, s["t"], g, inv_gib,
+                              tenant_axis=0)
+        acc = [out[n] for n in acc_names]
         return (u_next, b, v, usage + v, acc), utilization_codes(r)
 
     def epoch_step(carry, xs):
         e, d_ep = xs
         u, b, v_prev, usage, acc, ext = carry
-        desired = usage * (inv_ep * inv_r0_g)
+        desired = usage * (inv_ep * g.inv_r0)
         b_new = arbitrate(desired, m, weights=w, floors=fl,
                           priority_order=priority_order, policy=policy,
                           rr_offset=e - 1)
@@ -173,8 +164,8 @@ def _one_fleet_gain(demand, m, inv_m, w, fl, r0_g, lam_g, lam_grant_g,
                jnp.minimum(b_min, b.min(1)))
         return (u, b, v_prev, usage, acc, ext), codes
 
-    acc0 = (zeros, zeros, zeros, zeros, zeros, zeros, izeros, izeros,
-            jnp.full((n_nodes,), -1, jnp.int32), jnp.int32(0))
+    s0 = step.acc_init(zeros, izeros, carried_clock=True)
+    acc0 = [s0[n] for n in acc_names]
     ext0 = (jnp.float32(jnp.inf), jnp.float32(jnp.inf),
             jnp.zeros((k,), jnp.float32), jnp.full((k,), jnp.inf,
                                                    jnp.float32))
@@ -186,16 +177,10 @@ def _one_fleet_gain(demand, m, inv_m, w, fl, r0_g, lam_g, lam_grant_g,
         epoch_step, (u0, b0, v_prev0, usage0, acc0, ext0),
         (jnp.arange(n_epochs, dtype=jnp.int32), demand))
     _, _, _, _, acc, ext = carry
-    (us, _, cs, _, c2, mx, n_r0, n_viol, last_bad, _) = acc
     n_global = n_nodes * node_shards
-    n_steps = n_epochs * ep_len
-    p99 = quantile_from_codes(codes, 0.99, n_steps * n_global,
-                              axis_name=axis_name)
-    stats = finalize_fleet_stats(
-        util_sum=us, util_max=mx, caps_sum_gib=cs, caps_sumsq_gib=c2,
-        over_r0_count=n_r0, violation_count=n_viol, last_bad=last_bad,
-        p99_utilization=p99, r0=r0_g, n_intervals=n_steps,
-        interval_s=interval_s, axis_name=axis_name, n_nodes=n_global)
+    stats = step.finalize(dict(zip(acc_names, acc)), codes, r0_g,
+                          n_epochs * ep_len, n_global, interval_s,
+                          axis_name=axis_name)
     cons_min, floor_min, b_sum, b_min = ext
     if axis_name is not None:
         cons_min = jax.lax.pmin(cons_min, axis_name)
@@ -234,12 +219,9 @@ def _fleet_chunk_stats(demand, m, w, fl, r0, lam, lam_grant, u_min, u_max,
                                priority_order=priority_order,
                                axis_name=axis_name, node_shards=node_shards)
 
-    return jax.vmap(one_gain)(
-        jnp.asarray(r0, jnp.float32), jnp.asarray(lam, jnp.float32),
-        jnp.asarray(lam_grant, jnp.float32),
-        jnp.asarray(u_min, jnp.float32), jnp.asarray(u_max, jnp.float32),
-        jnp.asarray(deadband, jnp.float32),
-        jnp.asarray(feedforward, jnp.float32))
+    return jax.vmap(one_gain)(*(
+        jnp.asarray(c, jnp.float32)
+        for c in (r0, lam, lam_grant, u_min, u_max, deadband, feedforward)))
 
 
 @functools.lru_cache(maxsize=None)
@@ -318,13 +300,9 @@ def fleet_sweep_demand(
                   "fleet_sweep_demand(engine='pallas'): the two-level "
                   "fleet carry is not kernelized yet; falling back to "
                   "the XLA engine", RuntimeWarning)
-    demand = np.asarray(demand)
-    if demand.ndim != 3:
+    if np.ndim(demand) != 3:
         raise ValueError("demand must be (tenants, nodes, intervals)")
-    if horizon is not None:
-        if not 1 <= horizon <= demand.shape[2]:
-            raise ValueError(f"horizon must be in [1, {demand.shape[2]}]")
-        demand = demand[:, :, :horizon]
+    demand = _check_args(demand, horizon, node_shards=node_shards)
     k, n_nodes, n_steps = demand.shape
     if epoch_intervals < 1 or n_steps % epoch_intervals:
         raise ValueError(
@@ -338,8 +316,6 @@ def fleet_sweep_demand(
         priority_order = tuple(range(k))
     if sorted(priority_order) != list(range(k)):
         raise ValueError("priority_order must be a permutation of tenants")
-    if node_shards < 1:
-        raise ValueError("node_shards must be >= 1")
     n_epochs = n_steps // epoch_intervals
     # epoch-major (n_epochs, E, K, N): the outer scan's xs
     demand_e = np.ascontiguousarray(
@@ -347,26 +323,13 @@ def fleet_sweep_demand(
                                           n_nodes), dtype=np.float32)
     m = np.broadcast_to(np.asarray(node_memory, np.float64),
                         (n_nodes,)).astype(np.float32)
-    devs = resolve_devices(devices)
-    if len(devs) <= 1:
-        node_shards = 1
-    else:
-        if len(devs) % node_shards:
-            raise ValueError(f"devices ({len(devs)}) must divide evenly "
-                             f"into node_shards={node_shards}")
-        if n_nodes % node_shards:
-            raise ValueError(f"n_nodes ({n_nodes}) must be divisible by "
-                             f"node_shards={node_shards}")
+    devs, node_shards = _mesh_devices(devices, node_shards, n_nodes)
     gain_shards = len(devs) // node_shards
     chunk = min(FLEET_CHUNK if chunk is None else max(int(chunk), 1),
                 max(len(gains), 1))
     chunk = -(-chunk // gain_shards) * gain_shards
     n_real = len(gains)
-    if n_real % chunk:
-        pad = GainSet(*(np.repeat(getattr(gains, f.name)[-1:],
-                                  chunk - n_real % chunk)
-                        for f in dataclasses.fields(GainSet)))
-        gains = gains.concat(pad)
+    gains = _pad_gains(gains, chunk)
     fn = _compiled_fleet_sweep(devs, policy, tuple(priority_order),
                                node_shards)
     stage = _stager(devs, node_shards)
@@ -388,13 +351,8 @@ def fleet_sweep_demand(
         for cols in cols_per_chunk:
             pending.append(fn(demand_dev, m_dev, w_dev, fl_dev, *cols, iv))
     chunks = [jax.tree_util.tree_map(np.asarray, pair) for pair in pending]
-    stats = FleetStats(*(
-        np.concatenate([getattr(st, f) for st, _ in chunks])[:n_real]
-        for f in FleetStats._fields))
-    extras = FleetExtras(*(
-        np.concatenate([getattr(ex, f) for _, ex in chunks])[:n_real]
-        for f in FleetExtras._fields))
-    return stats, extras
+    return jax.tree_util.tree_map(lambda *a: np.concatenate(a)[:n_real],
+                                  *chunks)
 
 
 # ---------------------------------------------------------------------------

@@ -14,10 +14,7 @@ carry as per-node accumulators (Kahan-compensated float32 sums -- see
 streaming fixed-bin quantile (:mod:`~repro.lab.score`): utilization is
 quantized to ``uint16`` codes on a 65536-bin grid and the quantile is
 bisected out of the implicit histogram with 16 count reductions.  Each
-chunk therefore transfers O(G) scalars to the host -- the historical
-engine shipped the full ``(G, T, N)`` utilization history back for a
-numpy p99 (128 MB per 8-gain chunk at fleet scale), which capped chunk
-size and serialized every chunk behind a host sync.  Chunks are now
+chunk therefore transfers O(G) scalars to the host; chunks are
 dispatched asynchronously and collected once at the end.
 
 The gain axis also shards across devices: ``sweep_demand(...,
@@ -31,26 +28,16 @@ Gain chunks bound peak *device* memory (the uint16 code history is
 ``chunk x T x N x 2`` bytes); ``chunk=None`` picks the largest chunk
 within :data:`CODES_BUDGET_BYTES`.
 
-**CacheLoop**: a scenario with a :class:`~repro.lab.scenarios.CacheSpec`
-adds per-node cache state to the scan carry -- resident-set size, an
-analytic reuse-distance hit ratio, eviction/refill flux as the
-controller resizes the store, and a penalty model folding misses +
-evictions + the Fig.-2 pressure curve into modeled app runtime
-(:class:`~repro.lab.score.FleetStats` ``hit_ratio`` / ``evicted_bytes``
-/ ``app_runtime``).  The cache knobs are trace-time constants, so
-cache-off scenarios compile the exact pre-CacheLoop program, and a
-mixed paper/beyond-paper gain set is partitioned by law class
+**CacheLoop** (a :class:`~repro.lab.scenarios.CacheSpec`) adds per-node
+cache state to the scan carry and scores ``hit_ratio`` /
+``evicted_bytes`` / ``app_runtime``; **AppGraph** (an
+:class:`~repro.lab.appgraph.AppGraphSpec`) co-simulates the scenario's
+stage DAG in the same scan and scores ``makespan``.  The closed-loop
+step itself -- law, cache, queue, accumulators -- lives once in
+:mod:`.step`; this module is its XLA lowering.  A mixed
+paper/beyond-paper gain set is partitioned by law class
 (:func:`paper_law_mask`) so only the points with active beyond-paper
 knobs pay for the fallback executable.
-
-**AppGraph**: a scenario with an
-:class:`~repro.lab.appgraph.AppGraphSpec` co-simulates its stage DAG in
-the same scan -- per-node task queues drain at a rate stretched by the
-Fig.-2 pressure curve (and cache stalls), barrier stages promote on a
-fleet-wide ``pmin``, and stage transitions feed their held demand back
-into the trace the controller observes.  End-to-end wall clock streams
-out as ``FleetStats.makespan``; ``app_graph=None`` compiles the exact
-pre-AppGraph program.
 """
 
 from __future__ import annotations
@@ -69,16 +56,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..analysis.runtime import (count, dispatch_guard, record_trace,
                                 sanitizers_enabled, span)
+from . import step
 from ._compat import warn_once
-from ..core.control import ControllerParams, vectorized_step
-from ..core.eviction import policy_model
+from ..core.control import ControllerParams
 from ..core.traces import GiB
 from .appgraph import AppGraphSpec, compile_graph
 from .scenarios import CacheSpec, ScenarioSpec, get_scenario
-from .score import (FleetStats, OVER_R0_EPS, SETTLE_TOL, _axis_min,
-                    _axis_sum, default_score, finalize_fleet_stats,
-                    hpl_slowdown_curve, kahan_add, quantile_from_codes,
-                    utilization_codes)
+from .score import FleetStats, default_score
 
 # Upper bound on gains per compiled chunk; the auto-chunk logic lowers
 # it when the per-gain uint16 code history would blow the budget.
@@ -195,271 +179,65 @@ def _one_gain_stream(demand_tn, m, inv_m, r0_g, lam_g, lam_grant_g, u_min_g,
                      work_sn=None,
                      axis_name: Optional[str] = None,
                      node_shards: int = 1):
-    """Closed loop for one gain point, fully streamed.
+    """Closed loop for one gain point: the XLA lowering of :mod:`.step`
+    over ``(N,)`` node lanes.
 
-    The scan carry holds only per-node accumulators (O(N) state); the
-    sole scan output is the uint16 utilization code history consumed by
-    the in-program quantile bisection.  Nothing of size T x N is ever
-    staged for the host.
-
-    ``paper_law`` / ``unit_occupancy`` / ``static_bounds`` are
-    trace-time specializations (set by :func:`sweep_demand` after
-    inspecting the whole gain set): when every gain point is
-    paper-faithful -- symmetric gains, no deadband, no feedforward --
-    the slope state, the gain select and the hold branch drop out of
-    the hot loop entirely, and a gain set with uniform capacity bounds
-    clamps against compile-time constants instead of broadcast traced
-    scalars.  All paths produce identical results for parameters the
-    faster path admits.
-
-    With the node axis sharded across devices (``axis_name`` set, the
-    2-D gains x nodes mesh) the per-node lanes here are one shard's
-    slice: the closed loop itself is embarrassingly node-parallel, so
-    only the final stat folds and the streaming-quantile counts need
-    collectives -- both take ``axis_name`` and reduce over the *global*
-    fleet (``n_nodes * node_shards`` samples per interval).
-
-    ``cache`` (CacheLoop) swaps the saturated store for per-node cache
-    dynamics carried through the scan: the controller observes the
-    *resident set* (``v = d + resident``, the quantity cluster_sim's
-    monitor reads off the real ShardCache), shrinking the grant evicts
-    down to it immediately, and misses refill a grown grant read-
-    through up to the admission bandwidth.  The analytic hit curve
-    ``h(f) = c * f**(1-alpha) + (1-c) * f`` (see
-    :class:`~repro.core.eviction.PolicyModel`) converts the resident
-    fraction of the working set into a hit ratio; misses, eviction
-    churn, and the Fig.-2 pressure curve accumulate into modeled app
-    runtime.  The first pass over the working set is warmup-aware: the
-    resident set is seeded from ``warm_frac``, and until a node has
-    scanned its working set once a strictly cyclic workload
-    (``reuse_skew`` -> 0) pays compulsory misses for every block
-    outside the warm prefix -- parity-pinned against the
-    discrete-event simulator's cold start.  All cache knobs are
-    scenario constants, so the cache branch is resolved at trace time
-    -- ``cache=None`` compiles the exact pre-CacheLoop program.
-
-    ``app_graph`` (AppGraph) co-simulates the scenario's stage DAG in
-    the same scan: the carry gains per-node queue state (current stage
-    row, work remaining, Kahan work-done lanes) plus a scalar finish
-    time; each interval the active stage's held demand is added to the
-    observed demand *before* the controller sees it, the queue then
-    advances by ``compute_gibps * interval_s^2 / dt_eff`` where
-    ``dt_eff`` is the interval stretched by the Fig.-2 curve (and, with
-    a cache, miss/eviction stalls), and barrier rows promote only once
-    a fleet-wide min says every node finished the row.  Stage demand
-    constants and barrier flags bake in from the frozen spec; the
-    ``(S+1, N)`` per-node work matrix arrives as the traced ``work_sn``
-    operand (it depends on *global* node indices, which a node shard
-    cannot reconstruct locally).  Under the 2-D mesh the barrier /
-    completion folds are ``pmin`` collectives -- two scalar reductions
-    per step.  ``app_graph=None`` compiles the exact pre-AppGraph
-    program (the queue carry is the empty tuple).
+    The scan carry is the step's groups in order, then the clock ``t``
+    (O(N) state); the sole scan output is the uint16 utilization code
+    history the in-program quantile bisects.  ``static_bounds`` clamps
+    against compile-time constants when every gain point shares its
+    capacity bounds.  Under the 2-D gains x nodes mesh (``axis_name``
+    set) the lanes are one node shard: only the stat folds, the
+    quantile counts and the AppGraph barrier folds are collectives,
+    over the *global* fleet (``n_nodes * node_shards``); the ``(S+1,
+    N)`` AppGraph work matrix arrives as the traced ``work_sn``.
     """
     n_steps, n_nodes = demand_tn.shape
     if static_bounds is not None:
         u_min_g, u_max_g = static_bounds
     u0 = jnp.full((n_nodes,), u_max_g, jnp.float32)
     zeros = jnp.zeros((n_nodes,), jnp.float32)
-    # per-node event counters: int16 lanes (2x the SIMD width) whenever
-    # the horizon cannot overflow them
-    cnt_dtype = jnp.int16 if n_steps < 2**15 else jnp.int32
-    izeros = jnp.zeros((n_nodes,), cnt_dtype)
-    # Hoisted loop invariants: two reciprocals turn the law's divisions
-    # into multiplies for the T-step scan, and the threshold sums leave
-    # the hot path entirely.
-    inv_r0_g = 1.0 / r0_g
-    thr_over = r0_g + OVER_R0_EPS
-    thr_settle = r0_g + SETTLE_TOL
-    inv_gib = jnp.float32(1.0 / GiB)
+    izeros = jnp.zeros((n_nodes,), step.count_dtype(n_steps))
+    g = step.gain_rows(r0_g, lam_g, lam_grant_g, u_min_g, u_max_g, db_g,
+                       ff_g)
+    nodes = step.Rows((m, inv_m, None, None), step.NODE_ROWS)
+    wf0 = cc = q = None
     if cache is not None:
-        conc = float(policy_model(cache.policy).concentration)
-        hit_exp = 1.0 - float(cache.reuse_skew)
-        miss_pen = jnp.float32(cache.miss_penalty_s_per_gib)
-        evict_pen = jnp.float32(cache.evict_penalty_s_per_gib)
-        w = jnp.float32(cache.working_set_frac) * m        # (N,) bytes
-        inv_w = 1.0 / w
-        access_g = jnp.float32(cache.access_gibps) * interval_s  # GiB/itv
-        refill_b = jnp.float32(cache.refill_gibps * GiB) * interval_s
-        # Warmup-aware cold scan: constants of the first-pass term.
-        # The resident set is seeded from ``warm_frac`` of the initial
-        # grant; ``wf0`` is the warm-seeded fraction of the working set
-        # (the only blocks a strictly cyclic first pass can hit).
-        access_b = access_g * jnp.float32(GiB)             # bytes/itv
-        cold_mix = jnp.float32(cache.reuse_skew)
-        res0 = jnp.float32(cache.warm_frac) * jnp.minimum(u0, w)
-        wf0 = res0 * inv_w
+        w = step.working_set(cache, m, jnp.float32)
+        nodes = step.Rows((m, inv_m, w, 1.0 / w), step.NODE_ROWS)
+        cc = step.cache_consts(cache, interval_s, jnp.float32)
+        res0, wf0 = step.warm_seed(cc, u0, nodes)
     if app_graph is not None:
-        # Node-independent graph constants bake in from the frozen
-        # spec (stage-held demand, barrier flags); only the per-node
-        # work matrix is traced (see the docstring).  slow_nodes is a
-        # work-matrix concern, stripped so the 1-node compile passes
-        # range validation.
-        _cg = compile_graph(app_graph.replace(slow_nodes=()), 1)
-        n_stage_rows = _cg.n_rows
-        stage_demand_b = jnp.asarray(_cg.demand_bytes)     # (S+1,) bytes
-        stage_barrier = jnp.asarray(_cg.barrier)           # (S+1,) flags
-        comp_itv = jnp.float32(app_graph.compute_gibps) * interval_s
+        q = step.queue_consts(app_graph, interval_s, work_sn, axis_name)
+    loop = step.Loop(paper_law, unit_occupancy, occupancy, interval_s, cc, q,
+                     inv_gib=jnp.float32(1.0 / GiB))
+    names = step.carry_names(paper_law, cache is not None,
+                             app_graph is not None)
 
-    def saturated_usage(u, d):
-        return d + u if unit_occupancy else d + occupancy * u
+    def body(carry, d):
+        s = dict(zip(names, carry))
+        out, codes = step.step(s, d, s["t"], g, nodes, loop, wf0)
+        return [out[n] for n in names], codes
 
-    def step(carry, d):
-        law, cst, ags, acc = carry
-        (us, us_c, cs, cs_c, c2, mx, n_r0, n_viol, last_bad, t) = acc
-        u = law[0]
-        if app_graph is not None:
-            # An active stage holds its declared shuffle/scratch bytes:
-            # the controller observes demand *including* them, so stage
-            # entry/exit feeds back into the pressure the law reacts to.
-            sidx, wleft, wd, wd_c, t_done = ags
-            d = d + stage_demand_b[sidx]
-        if cache is None:
-            v = saturated_usage(u, d)                  # saturated store
-        else:
-            # The monitor sees what the store actually holds, not the
-            # grant: a freshly granted GiB is empty until refilled.
-            v = d + cst[0]
-        if paper_law:
-            v_eff = v
-        else:
-            # ``vectorized_step``'s own feedforward branch is resolved
-            # at trace time from a Python float, which a vmapped gain
-            # axis cannot feed; applying it to v up front is identical
-            # (the law uses v_eff everywhere v appears).
-            v_eff = v + ff_g * (v - law[1])
-        u_next = vectorized_step(
-            u, v_eff, total_memory=m, r0=r0_g, lam=lam_g,
-            u_min=u_min_g, u_max=u_max_g,
-            lam_grant=None if paper_law else lam_grant_g,
-            deadband=0.0 if paper_law else db_g,
-            inv_total_memory=inv_m, inv_r0=inv_r0_g)
-        r = v * inv_m
-        us, us_c = kahan_add(us, us_c, r)
-        cap_gib = u_next * inv_gib
-        cs, cs_c = kahan_add(cs, cs_c, cap_gib)
-        c2 = c2 + cap_gib * cap_gib
-        mx = jnp.maximum(mx, r)
-        n_r0 = n_r0 + (r > thr_over)
-        n_viol = n_viol + (r > 1.0)
-        last_bad = jnp.where(r > thr_settle, t, last_bad)
-        acc = (us, us_c, cs, cs_c, c2, mx, n_r0, n_viol, last_bad, t + 1)
-        if cache is not None:
-            resident, hs, hs_c, es, es_c, ts, ts_c = cst
-            # Actuation evicts down to the shrunk grant within the
-            # interval (the paper's "free space" RPC semantics);
-            # min/max forms keep the arithmetic exact when nothing
-            # changes.
-            res_ev = jnp.minimum(resident, u_next)
-            ev_g = (resident - res_ev) * inv_gib
-            f = jnp.minimum(res_ev * inv_w, 1.0)
-            hit = conc * f ** hit_exp + (1.0 - conc) * f
-            # Cold-scan term: until a node has scanned its working set
-            # once (compulsory-miss window), blocks refilled *within*
-            # the pass are not re-referenced by a cyclic scan, so at
-            # reuse_skew=0 only the warm-seeded prefix can hit; as the
-            # skew grows, intra-pass re-reference of hot blocks revives
-            # the steady-state curve.  ``reuse_skew`` interpolates
-            # between the two regimes; the warm prefix is clamped by
-            # the live resident fraction (eviction shrinks it too).
-            scanned = t.astype(jnp.float32) * access_b
-            wf = jnp.minimum(wf0, f)
-            hit = jnp.where(scanned < w,
-                            wf + cold_mix * (hit - wf), hit)
-            miss_g = (1.0 - hit) * access_g
-            # Read-through refill: only missed bytes repopulate the
-            # grant, capped by admission bandwidth, the grant itself,
-            # and the working set.
-            target = jnp.minimum(u_next, w)
-            resident = jnp.minimum(
-                target, res_ev + jnp.minimum(miss_g * jnp.float32(GiB),
-                                             refill_b))
-            dt_app = (interval_s * hpl_slowdown_curve(r)
-                      + miss_g * miss_pen + ev_g * evict_pen)
-            hs, hs_c = kahan_add(hs, hs_c, hit * access_g)
-            es, es_c = kahan_add(es, es_c, ev_g)
-            ts, ts_c = kahan_add(ts, ts_c, dt_app)
-            cst = (resident, hs, hs_c, es, es_c, ts, ts_c)
-        if app_graph is not None:
-            # Queue advance: the interval's wall clock stretches to
-            # dt_eff under pressure (and cache stalls), so the app
-            # makes interval_s / dt_eff of its nominal progress.
-            dt_eff = dt_app if cache is not None \
-                else interval_s * hpl_slowdown_curve(r)
-            active = sidx < n_stage_rows
-            adv = jnp.where(active, comp_itv * (interval_s / dt_eff), 0.0)
-            wd, wd_c = kahan_add(wd, wd_c, jnp.minimum(adv, wleft))
-            wleft = jnp.maximum(wleft - adv, 0.0)
-            fin = active & (wleft <= 0.0)
-            # Two-level progress code: 2*row, +1 once the row's work is
-            # drained.  A barrier row promotes only when the *fleet*
-            # min of the code says every node finished it (limplock:
-            # one slow node holds every node's code down).
-            lvl = sidx * 2 + fin.astype(jnp.int32)
-            fleet_lvl = _axis_min(jnp.min(lvl), axis_name)
-            can = fin & ((stage_barrier[sidx] == 0.0)
-                         | (fleet_lvl >= sidx * 2 + 1))
-            sidx = sidx + can.astype(jnp.int32)
-            wleft = jnp.where(
-                can, jnp.take_along_axis(work_sn, sidx[None, :], axis=0)[0],
-                wleft)
-            done_all = _axis_min(jnp.min(sidx), axis_name) >= n_stage_rows
-            t_done = jnp.where((t_done < 0.0) & done_all,
-                               (t + 1).astype(jnp.float32), t_done)
-            ags = (sidx, wleft, wd, wd_c, t_done)
-        law = (u_next,) if paper_law else (u_next, v)
-        return (law, cst, ags, acc), utilization_codes(r)
-
-    acc0 = (zeros, zeros, zeros, zeros, zeros, zeros, izeros, izeros,
-            jnp.full((n_nodes,), -1, jnp.int32), jnp.int32(0))
-    cst0 = ()
+    s0 = step.acc_init(zeros, izeros, carried_clock=True)
     if cache is not None:
-        cst0 = (res0, zeros, zeros, zeros, zeros, zeros, zeros)
-    ags0 = ()
+        s0.update(dict.fromkeys(step.CACHE, zeros), resident=res0)
     if app_graph is not None:
-        ags0 = (jnp.zeros((n_nodes,), jnp.int32), work_sn[0],
-                zeros, zeros, jnp.float32(-1.0))
-    if paper_law:
-        law0 = (u0,)
-    else:
+        s0.update(step.queue_init(q, zeros))
+    s0["u"] = u0
+    if not paper_law:
         # Seed v_prev with the first interval's usage so the slope term
         # is exactly zero before there is a previous observation
         # (matching the scalar loop's v_prev=None first step).
         d0 = demand_tn[0]
         if app_graph is not None:
-            d0 = d0 + stage_demand_b[0]
-        v0 = (saturated_usage(u0, d0) if cache is None
-              else d0 + cst0[0])
-        law0 = (u0, v0)
-    carry, codes = jax.lax.scan(step, (law0, cst0, ags0, acc0), demand_tn,
+            d0 = step.queue_observe(q, d0, 0)
+        s0["v_prev"] = step.observe(s0, d0, loop)
+    carry, codes = jax.lax.scan(body, [s0[n] for n in names], demand_tn,
                                 unroll=2)
-    _, cst, ags, acc = carry
-    (us, _, cs, _, c2, mx, n_r0, n_viol, last_bad, _) = acc
-    n_global = n_nodes * node_shards
-    p99 = quantile_from_codes(codes, 0.99, n_steps * n_global,
-                              axis_name=axis_name)
-    cache_kw = {}
-    if cache is not None:
-        cache_kw = dict(hits_gib=cst[1], evicted_gib=cst[3],
-                        app_time_s=cst[5],
-                        accesses_gib=access_g * n_steps)
-    if app_graph is not None:
-        # Finished: the recorded interval count.  Unfinished: the
-        # work-linear extrapolation (clamped to at least the horizon)
-        # so truncated runs still order by real progress.
-        _, _, wd, _, t_done = ags
-        total_w = _axis_sum(jnp.sum(work_sn), axis_name)
-        done_w = _axis_sum(wd, axis_name)
-        horizon_s = jnp.float32(n_steps) * interval_s
-        cache_kw["makespan_s"] = jnp.where(
-            t_done >= 0.0, t_done * interval_s,
-            jnp.maximum(horizon_s * total_w / jnp.maximum(done_w, 1e-6),
-                        horizon_s))
-    return finalize_fleet_stats(
-        util_sum=us, util_max=mx, caps_sum_gib=cs, caps_sumsq_gib=c2,
-        over_r0_count=n_r0, violation_count=n_viol, last_bad=last_bad,
-        p99_utilization=p99, r0=r0_g, n_intervals=n_steps,
-        interval_s=interval_s, axis_name=axis_name, n_nodes=n_global,
-        **cache_kw)
+    return step.finalize(dict(zip(names, carry)), codes, r0_g, n_steps,
+                         n_nodes * node_shards, interval_s, cache=cc,
+                         queue=q, axis_name=axis_name)
 
 
 def _chunk_stats(demand_tn, m, r0, lam, lam_grant, u_min, u_max, deadband,
@@ -472,22 +250,15 @@ def _chunk_stats(demand_tn, m, r0, lam, lam_grant, u_min, u_max, deadband,
                  axis_name: Optional[str] = None, node_shards: int = 1):
     """One gain chunk: scan over T, vmap over gains -> (G,)-field stats.
 
-    ``demand_tn`` is ``(T, N)`` bytes (shared by every gain point),
-    ``m`` is ``(N,)`` bytes, gain arrays are ``(G,)``; ``interval_s``
-    and ``occupancy`` ride along as traced scalars so every
-    (chunk, T, specialization, cache spec) tuple maps to exactly one
-    executable.  ``spec`` is :func:`_spec_digest` of the enclosing
-    :func:`_compiled_sweep` cache key, so the recompile-counter key
-    below distinguishes every legitimately separate executable.
-    Under the 2-D mesh ``demand_tn``/``m`` are one node shard and
-    ``axis_name``/``node_shards`` make the stat folds collective.
+    ``demand_tn`` is ``(T, N)`` bytes, ``m`` ``(N,)`` bytes, gain arrays
+    ``(G,)``; ``interval_s`` and ``occupancy`` ride along as traced
+    scalars.  Under the 2-D mesh ``demand_tn``/``m`` are one node shard
+    and ``axis_name``/``node_shards`` make the stat folds collective.
     """
-    # Trace-time only (Python in a jitted body runs once per compile):
-    # the recompile counter the sanitizer fixtures and --smoke assert
-    # on.  The key must be one-to-one with the executable cache key --
-    # shapes from the operands, everything else (devices, plan, full
-    # CacheSpec, mesh shape) folded into the spec digest -- or distinct
-    # CacheSpecs at the same shape would false-positive the gate.
+    # Trace-time only: the recompile counter the sanitizer fixtures
+    # assert on.  Its key must be one-to-one with the executable cache
+    # key -- shapes from the operands, the rest of the key (devices,
+    # plan, full CacheSpec, mesh shape) in ``spec``, its digest.
     record_trace("lab.sweep.chunk", chunk=int(r0.shape[0]),
                  horizon=int(demand_tn.shape[0]),
                  nodes=int(demand_tn.shape[1]),
@@ -508,32 +279,23 @@ def _chunk_stats(demand_tn, m, r0, lam, lam_grant, u_min, u_max, deadband,
                                 axis_name=axis_name,
                                 node_shards=node_shards)
 
-    return jax.vmap(one_gain)(
-        jnp.asarray(r0, jnp.float32), jnp.asarray(lam, jnp.float32),
-        jnp.asarray(lam_grant, jnp.float32),
-        jnp.asarray(u_min, jnp.float32), jnp.asarray(u_max, jnp.float32),
-        jnp.asarray(deadband, jnp.float32),
-        jnp.asarray(feedforward, jnp.float32))
+    return jax.vmap(one_gain)(*(
+        jnp.asarray(c, jnp.float32)
+        for c in (r0, lam, lam_grant, u_min, u_max, deadband, feedforward)))
 
 
-def _spec_digest(devices: Tuple, paper_law: bool, unit_occupancy: bool,
-                 static_bounds: Optional[Tuple[float, float]],
-                 cache: Optional[CacheSpec], node_shards: int = 1,
-                 app_graph: Optional[AppGraphSpec] = None) -> str:
-    """Short stable digest of one :func:`_compiled_sweep` cache key.
+def _spec_digest(*parts) -> str:
+    """Short stable digest of one compiled-program cache key.
 
-    Folded into the ``lab.sweep.chunk`` recompile-counter dims so the
-    counter key is one-to-one with the executables that legitimately
-    exist: two :class:`CacheSpec`\\ s (or device tuples, mesh shapes,
-    or bound specializations) at the same shape compile separately and
-    must count separately.  ``repr`` of a frozen dataclass / device
-    string is deterministic, so the digest is stable across processes
-    too.
+    Folded into the recompile-counter dims (``lab.sweep.chunk``,
+    ``lab.sweep.pallas``) so the counter key is one-to-one with the
+    executables that legitimately exist: two :class:`CacheSpec`\\ s (or
+    device tuples, mesh shapes, or bound specializations) at the same
+    shape compile separately and must count separately.  ``repr`` of a
+    frozen dataclass / device string is deterministic, so the digest is
+    stable across processes too.
     """
-    key = repr((tuple(str(d) for d in devices), paper_law,
-                unit_occupancy, static_bounds, cache, node_shards,
-                app_graph))
-    return hashlib.sha1(key.encode()).hexdigest()[:12]
+    return hashlib.sha1(repr(parts).encode()).hexdigest()[:12]
 
 
 @functools.lru_cache(maxsize=None)
@@ -543,24 +305,18 @@ def _compiled_sweep(devices: Tuple, paper_law: bool, unit_occupancy: bool,
                     app_graph: Optional[AppGraphSpec] = None):
     """Jitted chunk program for a device tuple (sharded when > 1).
 
-    With ``node_shards == 1`` the gain axis is split over a 1-D
-    ``("gains",)`` mesh with ``shard_map``; demand and node memory
-    replicate and per-gain programs are identical to the single-device
-    path, so sharding changes only placement, not results.
-
-    With ``node_shards > 1`` the devices form a 2-D
-    ``("gains", "nodes")`` mesh: the gain axis splits as before and the
-    node axis of demand / node memory splits ``node_shards`` ways, so
-    fleets too large for one device's code-history budget shard too.
-    Per-gain closed loops stay node-local; only the final stat folds
-    run ``psum``/``pmax`` collectives over ``"nodes"`` (every output is
-    therefore replicated along that axis).  Collective summation
-    reassociates float adds, so node-sharded stats match the unsharded
-    ones to reduction tolerance, not bitwise -- the single-device
-    fallback below stays the bit-exact reference.
+    ``node_shards == 1``: the gain axis splits over a 1-D ``("gains",)``
+    mesh; demand and node memory replicate, and per-gain programs are
+    the single-device ones, so sharding changes placement, not results.
+    ``node_shards > 1``: a 2-D ``("gains", "nodes")`` mesh also splits
+    the node axis, so fleets too large for one device's code budget
+    shard too; the stat folds run ``psum``/``pmax`` over ``"nodes"``,
+    which reassociates float adds (reduction tolerance, not bitwise --
+    one device stays the bit-exact reference).
     """
-    spec = _spec_digest(devices, paper_law, unit_occupancy, static_bounds,
-                        cache, node_shards, app_graph)
+    spec = _spec_digest(tuple(str(d) for d in devices), paper_law,
+                        unit_occupancy, static_bounds, cache, node_shards,
+                        app_graph)
     fn = functools.partial(_chunk_stats, paper_law=paper_law,
                            unit_occupancy=unit_occupancy,
                            static_bounds=static_bounds, cache=cache,
@@ -680,6 +436,42 @@ def resolve_devices(devices: Union[None, int, Sequence] = None) -> Tuple:
     return tuple(devices)
 
 
+def _check_args(demand, horizon: Optional[int], *,
+                cache: Optional[CacheSpec] = None, occupancy: float = 1.0,
+                node_shards: int = 1, precision: str = "f32") -> np.ndarray:
+    """The checks every sweep entry point makes; returns ``demand`` cut
+    to its first ``horizon`` intervals (the last axis)."""
+    demand = np.asarray(demand)
+    if cache is not None and float(occupancy) != 1.0:
+        raise ValueError("cache modeling replaces the occupancy "
+                         "abstraction; need occupancy == 1.0")
+    if node_shards < 1:
+        raise ValueError("node_shards must be >= 1")
+    if precision not in ("f32", "bf16"):
+        raise ValueError("precision must be f32|bf16")
+    if horizon is not None:
+        if not 1 <= horizon <= demand.shape[-1]:
+            raise ValueError(f"horizon must be in [1, {demand.shape[-1]}]")
+        demand = demand[..., :horizon]
+    return demand
+
+
+def _mesh_devices(devices, node_shards: int, n_nodes: int):
+    """``(devices, node_shards)`` of a sweep.  One device always runs
+    the plain jitted program, whatever ``node_shards`` was requested:
+    the bit-exact fallback."""
+    devs = resolve_devices(devices)
+    if len(devs) <= 1:
+        return devs, 1
+    if len(devs) % node_shards:
+        raise ValueError(f"devices ({len(devs)}) must divide evenly into "
+                         f"node_shards={node_shards}")
+    if n_nodes % node_shards:
+        raise ValueError(f"n_nodes ({n_nodes}) must be divisible by "
+                         f"node_shards={node_shards}")
+    return devs, node_shards
+
+
 def _resolve_chunk(chunk: Optional[int], n_gains: int, n_steps: int,
                    n_nodes: int, n_dev: int) -> int:
     """Gains per compiled call: memory-capped, device-divisible.
@@ -761,14 +553,12 @@ def sweep_demand(
     this, and ``cluster_sim.simulate_fleet`` feeds it the historical
     fleet workload directly.  Returns ``(G,)``-field stats as numpy.
 
-    ``engine`` selects the backend: ``"xla"`` (this module's scan+vmap
-    engine) or ``"pallas"`` (the fused kernel in
-    :mod:`~repro.lab.pallas_sweep`, parity-pinned to this one; pass
-    pallas-only knobs like ``precision=`` by calling
-    :func:`~repro.lab.pallas_sweep.pallas_sweep_demand` directly).
-    ``horizon`` truncates the loop to the first ``horizon`` intervals
-    -- the same knob every sweep entry point takes since the PR-9 API
-    unification.
+    ``engine`` selects the backend: ``"xla"`` (this module) or
+    ``"pallas"`` (:func:`~repro.lab.pallas_sweep.pallas_sweep_demand`,
+    which takes pallas-only knobs like ``precision=``).  ``horizon``
+    truncates the loop to the first ``horizon`` intervals.  ``cache``
+    enables CacheLoop, ``app_graph`` the stage-DAG co-simulation scored
+    through ``FleetStats.makespan`` (see :mod:`.step`).
 
     Every chunk is dispatched before any result is collected, so on an
     asynchronous backend chunk k+1 computes while chunk k's (G,)-scalar
@@ -776,23 +566,15 @@ def sweep_demand(
     ``lab.sweep.stage`` / ``dispatch`` / ``drain`` / ``merge`` and
     count ``lab.sweep.chunks`` and ``lab.sweep.lane_steps.{live,run}``
     (:mod:`repro.analysis.runtime`); the demand goes up once a call
-    (:func:`stage_demand`).  ``devices`` shards the gain axis
-    (see module docs); ``node_shards > 1`` additionally splits the node
-    axis, forming a
-    2-D ``(gains x nodes)`` mesh -- ``len(devices)`` must be divisible
-    by ``node_shards`` and ``N`` by the shard count.  Chunking and
-    sharding are implementation details -- stats are independent of
-    both (node-sharded float sums to reduction tolerance; with one
-    device the plain-jit path is taken and results are bit-identical
-    regardless of ``node_shards``).  ``cache`` enables CacheLoop (see
-    :class:`~repro.lab.scenarios.CacheSpec`); a gain set mixing
-    paper-faithful and beyond-paper points is partitioned by law class
-    so each class runs its own specialized executable on the same
-    staged operands.  ``app_graph``
-    attaches a stage-DAG co-simulation
-    (:class:`~repro.lab.appgraph.AppGraphSpec`) scored through
-    ``FleetStats.makespan``; ``None`` compiles the exact pre-AppGraph
-    program.
+    (:func:`stage_demand`).  ``devices`` shards the gain axis;
+    ``node_shards > 1`` also splits the node axis over a 2-D
+    ``(gains x nodes)`` mesh -- ``len(devices)`` must be divisible by
+    ``node_shards`` and ``N`` by the shard count.  Stats do not depend
+    on chunking or sharding (node-sharded float sums to reduction
+    tolerance; one device always runs the bit-exact plain jit).  A gain
+    set mixing paper-faithful and beyond-paper points runs each law
+    class on its own specialized executable over the same staged
+    operands.
     """
     if _resolve_engine(engine, "sweep_demand") == "pallas":
         from .pallas_sweep import pallas_sweep_demand
@@ -800,30 +582,11 @@ def sweep_demand(
             demand, gains, node_memory=node_memory, interval_s=interval_s,
             occupancy=occupancy, chunk=chunk, devices=devices, cache=cache,
             app_graph=app_graph, node_shards=node_shards, horizon=horizon)
-    demand = np.asarray(demand)
-    if cache is not None and float(occupancy) != 1.0:
-        raise ValueError("cache modeling replaces the occupancy "
-                         "abstraction; need occupancy == 1.0")
-    if node_shards < 1:
-        raise ValueError("node_shards must be >= 1")
-    if horizon is not None:
-        if not 1 <= horizon <= demand.shape[1]:
-            raise ValueError(f"horizon must be in [1, {demand.shape[1]}]")
-        demand = demand[:, :horizon]
+    demand = _check_args(demand, horizon, cache=cache, occupancy=occupancy,
+                         node_shards=node_shards)
     n_nodes, n_steps = demand.shape
     with span("lab.sweep.stage"):
-        devs = resolve_devices(devices)
-        if len(devs) <= 1:
-            # The bit-exact fallback: one device always runs the plain
-            # jitted program, whatever node_shards was requested.
-            node_shards = 1
-        else:
-            if len(devs) % node_shards:
-                raise ValueError(f"devices ({len(devs)}) must divide "
-                                 f"evenly into node_shards={node_shards}")
-            if n_nodes % node_shards:
-                raise ValueError(f"n_nodes ({n_nodes}) must be divisible "
-                                 f"by node_shards={node_shards}")
+        devs, node_shards = _mesh_devices(devices, node_shards, n_nodes)
         # Stage every operand device-side (f32) exactly once, before the
         # guarded dispatch loops, so the loop bodies are transfer-free
         # (which dispatch_guard() enforces under PLANECHECK_SANITIZERS=1).

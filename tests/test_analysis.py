@@ -454,16 +454,24 @@ def test_rule_catalog_covers_both_families():
 # ---------------------------------------------------------------------------
 
 def test_gate_fails_on_injected_item_in_scan(tmp_path):
-    src = open(os.path.join(REPO, "src/repro/lab/sweep.py")).read()
-    needle = "        law = (u_next,) if paper_law else (u_next, v)"
+    # The scan step lives in lab/step.py and is reached from the XLA
+    # engine's lax.scan in lab/sweep.py: analyze both, as a package.
+    lab = os.path.join(REPO, "src/repro/lab")
+    pkg = tmp_path / "lab"
+    pkg.mkdir()
+    (pkg / "__init__.py").write_text("")
+    (pkg / "sweep.py").write_text(open(os.path.join(lab, "sweep.py")).read())
+    src = open(os.path.join(lab, "step.py")).read()
+    needle = '    out["u"] = u_next\n'
     assert needle in src
-    mutated = tmp_path / "sweep_mut.py"
+    mutated = pkg / "step.py"
     mutated.write_text(src.replace(
-        needle, "        _bad = r.item()\n" + needle, 1))
-    findings = analyze_traced([str(mutated)], root=str(tmp_path))
+        needle, "    _bad = r.item()\n" + needle, 1))
+    paths = [str(pkg / "sweep.py"), str(mutated)]
+    findings = analyze_traced(paths, root=str(tmp_path))
     assert any(f.rule == "PC-T001" and "step" in f.symbol
                for f in findings)
-    rc = planecheck_main([str(mutated), "--check", "--baseline", BASELINE])
+    rc = planecheck_main(paths + ["--check", "--baseline", BASELINE])
     assert rc == 1
 
 

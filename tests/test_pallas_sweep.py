@@ -6,8 +6,9 @@ Four pins hold the PR-9 engine in place:
   ``engine="xla"`` stat for stat on the registry scenarios, bit for
   bit on the saturated-store and the cache paths alike;
 * **lowering parity** -- the production CPU scan and the true
-  ``pallas_call`` interpret-mode kernel share ``_fused_step``, so they
-  must agree bit for bit, deterministically across runs;
+  ``pallas_call`` interpret-mode kernel lower the same step
+  (``repro.lab.step``), so they must agree bit for bit,
+  deterministically across runs;
 * **in-scan halving identity** -- the device-side successive-halving
   program must select the same survivors and return the same tuned
   params as the host-loop ``halving_tune`` it replaces;

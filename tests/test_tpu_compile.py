@@ -25,6 +25,7 @@ from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.ssm_scan.kernel import ssm_scan
 from repro.lab import get_scenario, grid_gains
 from repro.lab import pallas_sweep as ps
+from repro.lab import step
 from repro.lab import sweep as sw
 
 N_NODES, N_STEPS = 4096, 3000
@@ -85,13 +86,11 @@ def test_halving_program_names_its_kernel_and_rungs(one_chip):
     gains = _gains(n_cand)
     base = ps.GainSet.from_params(PAPER_TABLE_I)
     plan = ps.plan_specialization(gains.concat(base))
-    con = ps._engine_consts(plan, None, 0.1, 1.0, "f32")
+    loop = step.Loop.bake(plan, None, 0.1, 1.0)
     horizons, keeps = ps.halving_schedule(steps, n_cand, (0.125, 0.5, 1.0),
                                           0.25, 4)
-    fn = ps._compiled_halving("mosaic", con,
-                              ps._state_names(con.paper_law, con.has_cache),
-                              tuple(horizons), tuple(keeps), n_cand, 1,
-                              ps.default_score)
+    fn = ps._compiled_halving("mosaic", loop, "f32", tuple(horizons),
+                              tuple(keeps), n_cand, 1, ps.default_score)
     lanes = 2 * ps.TILE_GAINS
     shapes = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
               for s in ((steps, nodes), (ps._N_NODE_ROWS, nodes),
